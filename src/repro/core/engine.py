@@ -84,9 +84,8 @@ Backend selection
   (structurally, or measured by routing the first chunk — see
   :func:`cluster_major_variant`).
 
-``interpret`` for the Pallas kernels is auto-detected from the
-platform (off-TPU ⇒ interpreter) and can be forced with the
-``REPRO_PALLAS_COMPILE=1`` env var, matching kernels/ops.py. Backends
+``interpret`` for the Pallas kernels follows the platform alone
+(off-TPU ⇒ interpreter), matching kernels/ops.py. Backends
 are bit-compatible: parity across shapes, padding, ties, and ``cr`` is
 enforced by tests/test_query_engine_parity.py.
 """
@@ -117,6 +116,11 @@ _CM_TWIN = {"pallas": "pallas-cm", "dense": "dense-cm"}
 # cluster at least this many times under query-major execution
 CLUSTER_MAJOR_DEDUP_THRESHOLD = 2.0
 
+# roster slots per cluster-major plan row: bounds the (Qcap, d) query
+# block the pallas-cm kernel holds in VMEM; a cluster routed by more
+# (query, route) pairs spills onto further rows (serving.cluster_major_plan)
+CLUSTER_MAJOR_QCAP = 128
+
 # traced plans an engine keeps before evicting least-recently-used ones
 DEFAULT_PLAN_CACHE_SIZE = 32
 
@@ -145,9 +149,9 @@ TOMBSTONE_K_BUCKET = 32
 
 
 def default_interpret() -> bool:
-    """Interpret-mode default for the Pallas kernels: compiled on TPU (or
-    when forced via REPRO_PALLAS_COMPILE=1), interpreted everywhere else.
-    Shared with kernels/ops.py so every entry point agrees."""
+    """Interpret-mode default for the Pallas kernels: compiled on TPU,
+    interpreted everywhere else. Shared with kernels/ops.py so every
+    entry point agrees."""
     from repro.kernels import ops as kops
     return kops._interpret_default()
 
@@ -157,9 +161,7 @@ def resolve_backend(backend: str = "auto",
     """→ (backend ∈ {"pallas", "dense"}, interpret flag for pallas).
 
     "auto" keys on the HARDWARE (pallas iff a TPU backend is present),
-    not on the interpret flag — REPRO_PALLAS_COMPILE=1 on a CPU host
-    must not route auto callers into a Mosaic lowering that cannot
-    compile there."""
+    not on the interpret flag."""
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
     interpret = default_interpret() if interpret is None else interpret
@@ -429,8 +431,9 @@ def _routed_topk(q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids,
         b = q_emb.shape[0]
         cr = top_c.shape[1]
         n = b * cr
+        qcap = min(-(-n // 8) * 8, CLUSTER_MAJOR_QCAP)
         u, roster, _, _ = serving_lib.cluster_major_plan(
-            top_c, n_clusters=buf_emb.shape[0])
+            top_c, n_clusters=buf_emb.shape[0], qcap=qcap)
         qidx = serving_lib.roster_query_rows(roster, cr=cr, n_total=n)
         q_filt_r = q_filt[qidx] if q_filt is not None else None
         ps, pi = fts.fused_topk_score_cluster_major(

@@ -30,6 +30,7 @@ mask are bit-identical across precision tiers — only TRel quantizes.
 """
 from __future__ import annotations
 
+import heapq
 import math
 from typing import Optional, Tuple
 
@@ -47,6 +48,9 @@ PRECISIONS = ("f32", "bf16", "int8")
 # Both the build path and the mutation path MUST use the same value, or
 # a mutated index diverges bit-wise from a rebuilt one.
 PAD_LOC = 1e6
+
+# buffer rows gathered per packing step of build_cluster_buffers
+_PACK_ROWS = 1 << 18
 
 
 # ---------------------------------------------------------------------------
@@ -197,6 +201,13 @@ def assign_clusters(params, feats, *, top=1):
     return jax.lax.top_k(logits, top)[1]
 
 
+def default_capacity(n_objects: int, n_clusters: int) -> int:
+    """Slots per cluster buffer: twice the mean cluster size, rounded up
+    to a multiple of 128 (the scan kernels' lane-aligned tile)."""
+    capacity = int(math.ceil(n_objects / n_clusters * 2.0))
+    return -(-capacity // 128) * 128
+
+
 def build_cluster_buffers(assign_top, emb, loc, *, n_clusters: int,
                           capacity: Optional[int] = None, spill: int = 3,
                           precision: str = "f32", attrs=None):
@@ -218,39 +229,51 @@ def build_cluster_buffers(assign_top, emb, loc, *, n_clusters: int,
     n, d = emb.shape
     c = n_clusters
     if capacity is None:
-        capacity = int(math.ceil(n / c * 2.0))
-        capacity = -(-capacity // 128) * 128
-    counts = np.zeros(c, np.int64)
-    ids = np.full((c, capacity), -1, np.int32)
+        capacity = default_capacity(n, c)
+    counts = [0] * c
+    members = [[] for _ in range(c)]
+    # the least-loaded cluster, as np.argmin(counts) picks it (lowest
+    # count, then lowest id): one (count, cluster) entry per cluster,
+    # refreshed only when it reaches the top stale (counts only grow, so
+    # a stale entry sorts too early, never too late)
+    least = [(0, ci) for ci in range(c)]
     n_spilled = 0
-    for i in range(n):
-        placed = False
-        for h in range(min(spill, assign_top.shape[1])):
-            ci = int(assign_top[i, h])
+    for i, hops in enumerate(assign_top[:, :spill].tolist()):
+        for h, ci in enumerate(hops):
             if counts[ci] < capacity:
-                ids[ci, counts[ci]] = i
-                counts[ci] += 1
-                placed = True
-                if h > 0:
-                    n_spilled += 1
                 break
-        if not placed:  # everything full: force into least-loaded cluster
-            ci = int(np.argmin(counts))
+        else:  # every preferred cluster is full: the least-loaded one
+            while least[0][0] != counts[least[0][1]]:
+                heapq.heapreplace(least, (counts[least[0][1]], least[0][1]))
+            ci = least[0][1]
             if counts[ci] >= capacity:
                 raise ValueError("cluster capacity exhausted; raise capacity")
-            ids[ci, counts[ci]] = i
-            counts[ci] += 1
-            n_spilled += 1
+            h = -1
+        members[ci].append(i)
+        counts[ci] += 1
+        n_spilled += h != 0
+    ids = np.full((c, capacity), -1, np.int32)
+    for ci, m in enumerate(members):
+        ids[ci, :len(m)] = m
+    counts = np.asarray(counts, np.int64)
     gather = np.where(ids >= 0, ids, 0)
-    buf_emb = emb[gather]
     buf_loc = loc[gather]
     buf_attrs = attrs[gather]
     valid = ids >= 0
-    # zero out padding so fused scores on pads are harmless (masked anyway)
-    buf_emb[~valid] = 0.0
     buf_loc[~valid] = PAD_LOC
     buf_attrs[~valid] = 0
-    buf_emb, buf_scale = quantize_rows(buf_emb, precision)
+    # embeddings are gathered and quantized a few clusters at a time, so
+    # only the stored tier's (c, cap, d) array is ever whole on the host
+    step = max(1, _PACK_ROWS // capacity)
+    buf_emb, buf_scale = None, np.ones((c, capacity), np.float32)
+    for c0 in range(0, c, step):
+        blk = emb[gather[c0:c0 + step]].astype(np.float32)
+        # zero out padding so fused scores on pads are harmless (masked)
+        blk[~valid[c0:c0 + step]] = 0.0
+        blk, buf_scale[c0:c0 + step] = quantize_rows(blk, precision)
+        if buf_emb is None:
+            buf_emb = np.empty((c, capacity, d), blk.dtype)
+        buf_emb[c0:c0 + step] = blk
     return {
         "emb": jnp.asarray(buf_emb), "loc": jnp.asarray(buf_loc),
         "ids": jnp.asarray(ids), "counts": jnp.asarray(counts),

@@ -46,20 +46,21 @@ from repro.optim import make_optimizer, clip_by_global_norm, linear_warmup_cosin
 
 def embed_objects(params, corpus, cfg, *, batch: int = 512) -> np.ndarray:
     tokens, mask = corpus.object_tokens()
-    return _embed(functools.partial(relevance.encode_objects, params, cfg=cfg),
-                  tokens, mask, batch)
+    return _embed(relevance.encode_objects, params, cfg, tokens, mask, batch)
 
 
 def embed_queries(params, corpus, cfg, query_ids=None, *,
                   batch: int = 512) -> np.ndarray:
     tokens, mask = corpus.query_tokens(query_ids)
-    return _embed(functools.partial(relevance.encode_queries, params, cfg=cfg),
-                  tokens, mask, batch)
+    return _embed(relevance.encode_queries, params, cfg, tokens, mask, batch)
 
 
-def _embed(encode, tokens, mask, batch):
-    jfn = jax.jit(lambda t, m: encode(t, m))
-    return engine_lib.run_batched(jfn, [tokens, mask], batch=batch)
+def _embed(encode, params, cfg, tokens, mask, batch):
+    # params enter as an argument: closed over, they would be baked into
+    # the executable as constants (hundreds of MB at BERT-base width)
+    jfn = jax.jit(functools.partial(encode, cfg=cfg))
+    return engine_lib.run_batched(lambda t, m: jfn(params, t, m),
+                                  [tokens, mask], batch=batch)
 
 
 # ---------------------------------------------------------------------------
@@ -111,7 +112,9 @@ def train_relevance_model(corpus, cfg, *, steps: int = 200, batch: int = 64,
     if negs is not None:
         neg_lookup[train_q] = negs
 
-    @jax.jit
+    # params and optimizer state are replaced every step: donating them
+    # lets the update reuse their device memory
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def step_fn(params, opt_state, batch_dev, lr_now):
         def loss_fn(p):
             return relevance.contrastive_loss(
@@ -186,7 +189,7 @@ def train_cluster_index(rel_params, corpus, cfg, *, obj_emb=None,
     opt_state = opt_init(iparams)
     sched = linear_warmup_cosine(lr, max(steps // 20, 1), steps)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def step_fn(iparams, opt_state, fb, lr_now):
         (loss, m), grads = jax.value_and_grad(
             index_lib.mcl_loss, has_aux=True)(iparams, fb)

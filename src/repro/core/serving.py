@@ -105,51 +105,58 @@ def cluster_major_plan(top_c, *, n_clusters: int,
 
     Where :func:`dispatch_queries` builds one roster row for every one
     of the ``c`` clusters (the sharded all-to-all layout), this dedupes
-    the batch's routed clusters and builds one row per **distinct**
-    routed cluster — the plan the cluster-major kernel
-    (``kernels.fused_topk_score_cluster_major``) streams: each distinct
-    cluster's resident tiles cross HBM once per batch, scored against
-    that cluster's whole query roster.
+    the batch's routed clusters and gives each **distinct** routed
+    cluster its own roster rows — the plan the cluster-major kernel
+    (``kernels.fused_topk_score_cluster_major``) streams: each row's
+    cluster tiles cross HBM once per batch, scored against that row's
+    whole query roster.
 
     top_c: (B, cr) routed cluster ids (duplicates allowed — a query
     routed twice to one cluster occupies two roster slots, preserving
-    the query-major duplicate semantics). Returns
+    the query-major duplicate semantics). ``qcap`` (default ``B·cr``)
+    is the width of a roster row: a cluster routed by more pairs than
+    that spills onto further rows, ``ceil(occupancy / qcap)`` in all.
+    Returns
 
-      u          (u_max,) int32 — the distinct routed cluster ids, one
-                 per roster row, in ascending cluster order. Slots past
-                 the realized distinct count ``U`` hold cluster 0 with
-                 an empty roster (static shapes: ``u_max`` defaults to
-                 ``min(B·cr, n_clusters)``, the structural upper bound
-                 on ``U``).
+      u          (u_max,) int32 — the cluster each roster row scans, in
+                 ascending cluster order. Rows past the realized count
+                 hold cluster 0 with an empty roster (static shapes:
+                 ``u_max`` defaults to the structural upper bound on the
+                 row count, ``min(B·cr, n_clusters)`` when nothing
+                 spills).
       roster     (u_max, qcap) int32 — the inverse map: flattened
                  (query, route) indices in ``[0, B·cr)`` assigned to
-                 each distinct cluster, ``B·cr`` marking empty slots.
-                 ``qcap`` defaults to ``B·cr`` (exact: nothing can
-                 drop); a smaller ``qcap`` bounds the roster like the
-                 dispatch capacity does.
-      n_distinct () int32 — the realized U; the batch dedup factor is
-                 ``B·cr / U`` (the auto heuristic's signal).
-      n_dropped  () int32 — (query, route) pairs that exceeded ``qcap``
-                 (or ``u_max``) and were NOT placed; surfaced, never
+                 each row, ``B·cr`` marking empty slots.
+      n_distinct () int32 — the realized distinct count U; the batch
+                 dedup factor is ``B·cr / U`` (the auto heuristic's
+                 signal).
+      n_dropped  () int32 — (query, route) pairs past a caller-forced
+                 ``u_max`` that were NOT placed; surfaced, never
                  silently truncated, exactly like the dispatch path.
+                 The default ``u_max`` places every pair.
     """
     b, cr = top_c.shape
     n = b * cr
-    u_max = min(n, n_clusters) if u_max is None else u_max
     qcap = n if qcap is None else qcap
+    if u_max is None:
+        # rows = Σ_u ceil(o_u / qcap) ≤ U + (n - U)/qcap, increasing in U
+        u_max = min(n, n_clusters)
+        if qcap < n:
+            u_max += -(-(n - u_max) // qcap)
     flat = top_c.reshape(n)
     sort_idx, sorted_c, is_start, pos = _sorted_runs(flat)
-    slot_of = jnp.cumsum(is_start) - 1            # distinct-slot per pair
-    n_distinct = slot_of[-1].astype(jnp.int32) + 1
-    keep = (pos < qcap) & (slot_of < u_max)
-    dest = jnp.where(keep, slot_of * qcap + pos, u_max * qcap)
+    n_distinct = jnp.sum(is_start).astype(jnp.int32)
+    row_start = pos % qcap == 0                   # includes every run start
+    row_of = jnp.cumsum(row_start) - 1            # roster row per pair
+    keep = row_of < u_max
+    dest = jnp.where(keep, row_of * qcap + pos % qcap, u_max * qcap)
     n_dropped = jnp.sum(~keep).astype(jnp.int32)
 
     roster = jnp.full((u_max * qcap + 1,), n, jnp.int32)
     roster = roster.at[dest].set(sort_idx.astype(jnp.int32))
     roster = roster[:-1].reshape(u_max, qcap)
 
-    u_dest = jnp.where(is_start & (slot_of < u_max), slot_of, u_max)
+    u_dest = jnp.where(row_start & keep, row_of, u_max)
     u = jnp.zeros((u_max + 1,), jnp.int32)
     u = u.at[u_dest].set(sorted_c.astype(jnp.int32))[:u_max]
     return u, roster, n_distinct, n_dropped

@@ -23,7 +23,7 @@ def _kernel(x_ref, o_ref):
     o_ref[...] = g.astype(o_ref.dtype)
 
 
-def dot_interaction(feats, *, block_m: int = 128, interpret: bool = True):
+def dot_interaction(feats, *, interpret: bool, block_m: int = 128):
     """feats: (B, F, d) → (B, F(F-1)/2) upper-triangle pairwise dots."""
     b, f, d = feats.shape
     block_m = min(block_m, b)

@@ -45,7 +45,7 @@ def _kernel(idx_ref, tab_ref, o_ref, *, block_v: int):
 
 
 def embedding_bag(table, idx, *, block_m: int = 256, block_v: int = 512,
-                  interpret: bool = True):
+                  interpret: bool):
     """table: (V, d); idx: (B, P) int32, -1 padded → pooled sums (B, d)."""
     v, d = table.shape
     b, p = idx.shape

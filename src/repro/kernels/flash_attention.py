@@ -81,7 +81,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: (B, S, H, D); k, v: (B, S, KV, D). Returns (B, S, H, D).
 
     H = KV · G. Sequences are padded to block multiples internally.

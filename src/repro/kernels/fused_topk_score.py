@@ -4,79 +4,60 @@ This is the op the paper's entire index exists to accelerate (Algorithm 1
 line 17): for each routed query, score every object in its cluster buffer
 with ST(q,o) = w_t·(q·o) + w_s·ŵ_s[⌊S_in·t⌋] and keep the top-k.
 
-TPU-native design (DESIGN.md §3/§4): the candidate buffer streams through
-VMEM in (block_n, d) tiles; each tile costs one (block_m × d × block_n)
-MXU matmul for TRel plus a vectorized O(1) step-table lookup for SRel
-(Eq. 5) — the spatial relevance never round-trips to HBM. A running top-k
-lives in the revisited output block: per tile we concatenate (k + block_n)
-candidates and re-top-k, so the merge cost is O(k+block_n · log) in VMEM.
-The workload is memory-bound (corpus streaming); fusing score + spatial +
-select into one pass is what reaches the HBM roofline.
+TPU-native design (DESIGN.md §3/§4): the resident ``(c, cap, d)``
+buffers stream through VMEM in ``(block_n, d)`` tiles; each tile costs
+one MXU matmul for TRel plus the step-table lookup for SRel (Eq. 5), and
+folds into a running top-k held in the revisited output block — the
+spatial relevance and the candidate scores never round-trip to HBM.
 
-Grid: (B/block_m, N/block_n), last dim innermost (sequential) so output
-revisiting is legal on TPU.
+Two kernels share one body (:func:`_scan_kernel`):
 
-Three variants live here:
+* :func:`fused_topk_score_routed` — query-major and gather-free. The
+  routed cluster ids are **scalar-prefetched**
+  (``pltpu.PrefetchScalarGridSpec``) so the BlockSpec index maps
+  block-index the resident buffers directly: grid step ``(b, r, j)`` DMAs
+  tile ``j`` of cluster ``top_c[b, r]`` — no candidate copy exists, and
+  the ``cr`` routed lists merge into one running top-k in VMEM. Output
+  ids are global object ids (read from ``buf_ids`` in-kernel).
+* :func:`fused_topk_score_cluster_major` — the batched-IVF inversion
+  (DESIGN.md §10). Grid ``(rows, cap/bn)`` scalar-prefetches the plan's
+  per-row cluster ids (``serving.cluster_major_plan``), DMAs each row's
+  cluster tiles once per batch, and scores them against the row's whole
+  query roster in one ``(Qcap, d) × (d, bn)`` matmul. The caller folds
+  the per-slot partial lists with ``engine.merge_cluster_major``.
 
-* :func:`fused_topk_score` — the original gather-path kernel. The caller
-  materializes a ``(B, cr·cap, d)`` candidate copy (``buf[top_c]``) and the
-  kernel streams that copy. Simple, but the gather itself is an HBM round
-  trip the size of the scanned corpus slice.
-* :func:`fused_topk_score_routed` — the gather-free kernel (DESIGN.md §4).
-  The routed cluster ids are **scalar-prefetched**
-  (``pltpu.PrefetchScalarGridSpec``) so the BlockSpec index maps can
-  block-index the resident ``(c, cap, d)`` buffers directly: grid step
-  ``(b, r, j)`` DMAs tile ``j`` of cluster ``top_c[b, r]`` straight from the
-  buffer — no candidate copy exists at any point, and the ``cr`` routed
-  lists merge into one running top-k in VMEM instead of a second host-side
-  top-k. Output ids are global object ids (taken from ``buf_ids`` in-kernel)
-  so the caller needs no ``take_along_axis`` either.
-* :func:`fused_topk_score_cluster_major` — the batched-IVF inversion of
-  the routed kernel (DESIGN.md §10). The routed kernel is query-major:
-  its ``(B, cr, cap/bn)`` grid re-streams a popular cluster's tiles once
-  per routed query, so under skewed routing the dominant HBM stream is
-  ``B·cr/U``× larger than the distinct-cluster working set ``U``. This
-  kernel runs the batch plan of ``serving.cluster_major_plan`` instead:
-  grid ``(u_max, cap/bn)`` scalar-prefetches the distinct routed
-  clusters ``u`` and their query roster, DMAs each distinct cluster's
-  tiles **once per batch**, and scores them against the cluster's whole
-  roster in a single ``(Qcap, d) × (d, bn)`` MXU matmul. Per-roster-slot
-  running top-k lives in the revisited ``(1, Qcap, k)`` output block;
-  the caller folds the ``cr`` partial lists per query with
-  ``engine.merge_cluster_major`` (a thin scatter + one top-k). With a
-  quantized buffer the dequant also happens once per distinct cluster
-  per batch, not once per route — the dedup and the precision cut
-  compose multiplicatively.
+What Mosaic (the TPU kernel compiler) accepts shapes the body:
 
-Precision policy (DESIGN.md §9): the roofline is set by streaming the
-candidate embeddings, so every kernel here grows a **dequant-in-kernel**
-variant for quantized resident buffers. When a per-row scale array is passed
-(``cand_scale`` / ``buf_scale``, int8 buffers), the compressed tile is
-DMA'd to VMEM, upcast to f32 and multiplied by its scales *there*, and
-then hits the same MXU matmul and running top-k — only compressed bytes
-ever cross HBM (4× less traffic than f32 for int8). bf16 buffers need no
-scale: the existing ``astype(f32)`` upcast handles them, halving traffic.
-Locations, ids, and the padding mask always stay exact, so SRel and the
-pad semantics are bit-identical across precision tiers. On a real TPU the
-int8 min tile is (32, 128), so pick ``block_n`` a multiple of 32 and keep
-``d`` a multiple of 128 for compiled int8 runs (interpret mode doesn't
-care).
+* every block's last two dims are multiples of (8, 128) or equal the
+  array's, so per-query and per-candidate vectors ride a leading
+  singleton axis (queries ``(B, 1, d)``, ids/scales ``(c, 1, cap)``) and
+  locations/attributes are lane-major (``(c, 2, cap)`` / ``(c, 3,
+  cap)``) — a minor dim of 2 or 3 would pad every row to 128 lanes;
+* the tile ``block_n`` divides ``cap`` and is a multiple of 128 (or all
+  of ``cap``);
+* there is no vector gather from a long table and no in-kernel
+  ``lax.top_k``: the step lookup is a lane gather from 128-entry chunks
+  of ŵ (:func:`_step_lookup`), and the merge is ``k`` rounds of max,
+  first index of the max, and mask (:func:`_merge_topk`), which keeps
+  ``lax.top_k``'s order: ties go to the running list, then to the lower
+  tile position.
 
-Filtered search (DESIGN.md §13): the routed and cluster-major kernels
-grow an in-VMEM **predicate mask** variant for multi-tenant / attribute
-filtering (core/filters.py). When a ``(c, cap, 3)`` int32 attribute
-buffer and per-query compiled filter rows (``q_filt (B, 4)`` /
-roster-gathered ``(u_max, Qcap, 4)``) are passed, each tile's attribute
-strip is DMA'd beside the embeddings and the predicate is evaluated
-right where the dequant happens: rows that fail score ``NEG_INF`` and
-their ids null to ``-1`` — exactly the padding semantics — so filtered
-candidates never round-trip to host and can never surface in a top-k.
-The unfiltered call path is byte-identical to before (no attrs bytes
-stream, same kernel body).
+Precision policy (DESIGN.md §9): int8 tiles are upcast in VMEM and their
+per-row scales applied to the matmul's output columns; bf16 tiles need
+no scale. The matmul runs at ``Precision.HIGHEST`` so the f32 upcast is
+exact on the chip too. Locations, ids, and the padding mask stay exact,
+so SRel and the pad semantics (id -1, ``NEG_INF``) are identical across
+precision tiers.
+
+Filtered search (DESIGN.md §13): with a ``(c, cap, 3)`` int32 attribute
+buffer and per-query compiled filter rows, each tile's attribute strip
+streams beside the embeddings and failing rows take the padding
+semantics in VMEM. The unfiltered call streams no attribute bytes.
 """
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -84,6 +65,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+_LANES = 128          # TPU vector lane width: tiles and table chunks
+_SUBLANES = 8
 
 
 def _largest_divisor_tile(size: int, requested: int) -> int:
@@ -94,532 +78,324 @@ def _largest_divisor_tile(size: int, requested: int) -> int:
     return tile
 
 
-def _predicate_tile(attrs, fvals):
+def _scan_tile(cap: int, block_n: int, *, who: str) -> int:
+    """The streaming tile over a capacity-``cap`` cluster: all of ``cap``
+    when it fits ``block_n``, else the largest multiple of 128 that
+    divides ``cap`` (a lane-aligned block the chip accepts). A capacity
+    with no such divisor falls back to its largest divisor ≤ ``block_n``
+    (interpret mode only) and warns if the tiles collapse."""
+    if cap <= block_n:
+        return cap
+    aligned = [t for t in range(_LANES, block_n + 1, _LANES) if cap % t == 0]
+    if aligned:
+        return max(aligned)
+    tile = _largest_divisor_tile(cap, block_n)
+    if tile < max(1, block_n // 4):
+        warnings.warn(
+            f"{who}: capacity {cap} has no divisor near the requested tile "
+            f"size ({block_n}); tiles collapsed to {tile} — pathological "
+            f"grid. Prefer a capacity with a large power-of-two factor "
+            f"(build_cluster_buffers rounds to multiples of 128)",
+            stacklevel=3)
+    return tile
+
+
+def _step_table(w_hat):
+    """ŵ ``(t,)`` → ``(ceil(t/128), 128)`` f32 chunks (edge-padded), the
+    form :func:`_step_lookup` gathers from."""
+    t = w_hat.shape[0]
+    n = -(-t // _LANES)
+    return jnp.pad(w_hat.astype(jnp.float32), (0, n * _LANES - t),
+                   mode="edge").reshape(n, _LANES)
+
+
+def _step_lookup(tab, idx):
+    """``ŵ[idx]`` for int32 ``idx (m, n)`` in ``[0, t)``. Mosaic gathers
+    only within one 128-lane vreg, so each 128-entry chunk of the table
+    is gathered per 128-lane slice of ``idx`` and selected by the chunk
+    number. A one-row ``idx`` is broadcast to a full 8-sublane tile."""
+    rows, n = idx.shape
+    if rows == 1:
+        idx = jnp.broadcast_to(idx, (_SUBLANES, n))
+    lo = idx % _LANES
+    hi = idx // _LANES
+    out = jnp.zeros(idx.shape, jnp.float32)
+    for c in range(tab.shape[0]):
+        chunk = jnp.broadcast_to(tab[c:c + 1, :], (idx.shape[0], _LANES))
+        got = jnp.concatenate(
+            [jnp.take_along_axis(chunk, lo[:, s:s + _LANES], axis=1)
+             for s in range(0, n, _LANES)], axis=1)
+        out = jnp.where(hi == c, got, out)
+    return out[:rows]
+
+
+def _predicate(attrs, fvals):
     """In-VMEM filter predicate (the kernel twin of
-    ``filters.predicate_mask``): ``attrs`` int32 ``(n, 3)`` candidate
-    attribute rows [tenant, category bitmask, timestamp]; ``fvals`` int32
+    ``filters.predicate_mask``): ``attrs`` int32 ``(3, n)`` lane-major
+    candidate rows [tenant; category bitmask; timestamp]; ``fvals`` int32
     ``(m, 4)`` compiled per-query filters [tenant, mask, t_min, t_max]
     with sentinel no-ops (tenant<0, mask==0, int32 extremes). Returns
     bool ``(m, n)`` — True = candidate passes that query's filter."""
-    tenant = attrs[None, :, 0]                       # (1, n)
-    cat = attrs[None, :, 1]
-    ts = attrs[None, :, 2]
-    f_tenant = fvals[:, 0:1]                         # (m, 1)
-    f_mask = fvals[:, 1:2]
-    t_lo = fvals[:, 2:3]
-    t_hi = fvals[:, 3:4]
+    tenant, cat, ts = attrs[0:1], attrs[1:2], attrs[2:3]        # (1, n)
+    f_tenant, f_mask = fvals[:, 0:1], fvals[:, 1:2]             # (m, 1)
+    t_lo, t_hi = fvals[:, 2:3], fvals[:, 3:4]
     ok_tenant = (f_tenant < 0) | (tenant == f_tenant)
     ok_cat = (f_mask == 0) | ((cat & f_mask) != 0)
     ok_time = (ts >= t_lo) & (ts <= t_hi)
     return ok_tenant & ok_cat & ok_time
 
 
-def _gather_body(q_ref, loc_ref, w_ref, wh_ref, ce, cl_ref, ci_ref,
-                 os_ref, oi_ref, *, k: int, t: int, dist_max: float,
-                 block_n: int):
-    """Score one (block_m, block_n) candidate tile (``ce`` already f32,
-    dequantized by the caller) and fold it into the running top-k."""
-    j = pl.program_id(1)
+def _merge_topk(run_s, run_i, st, ids, k: int):
+    """Fold a scored tile ``st``/``ids (m, n)`` into the running top-k
+    ``run_s``/``run_i (m, k)``: ``k`` rounds of (max, first index of the
+    max, mask it). Equals ``lax.top_k`` over ``concat([run, tile])``:
+    ties resolve to the lower concatenated position."""
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, run_s.shape, 1)
+    iota_n = jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+    big = jnp.int32(2 ** 30)
+    low = jnp.int32(-2 ** 31)
 
-    @pl.when(j == 0)
+    def one(r, carry):
+        run_s, st, out_s, out_i = carry
+        m_run = jnp.max(run_s, axis=1, keepdims=True)
+        m_tile = jnp.max(st, axis=1, keepdims=True)
+        from_run = m_run >= m_tile
+        p_run = jnp.min(jnp.where(run_s == m_run, iota_k, big), axis=1,
+                        keepdims=True)
+        p_tile = jnp.min(jnp.where(st == m_tile, iota_n, big), axis=1,
+                         keepdims=True)
+        hit_run = from_run & (iota_k == p_run)
+        hit_tile = jnp.logical_not(from_run) & (iota_n == p_tile)
+        i_run = jnp.max(jnp.where(hit_run, run_i, low), axis=1,
+                        keepdims=True)
+        i_tile = jnp.max(jnp.where(hit_tile, ids, low), axis=1,
+                         keepdims=True)
+        here = iota_k == r
+        out_s = jnp.where(here, jnp.maximum(m_run, m_tile), out_s)
+        out_i = jnp.where(here, jnp.where(from_run, i_run, i_tile), out_i)
+        run_s = jnp.where(hit_run, -jnp.inf, run_s)
+        st = jnp.where(hit_tile, -jnp.inf, st)
+        return run_s, st, out_s, out_i
+
+    _, _, out_s, out_i = jax.lax.fori_loop(
+        0, k, one, (run_s, st, jnp.zeros_like(run_s), jnp.zeros_like(run_i)))
+    return out_s, out_i
+
+
+def _scan_kernel(*refs, n_prefetch: int, first_step, dequant: bool,
+                 filtered: bool, k: int, t: int, dist_max: float):
+    """Score one ``(block_n, d)`` resident tile against ``m`` query rows
+    (1 for the routed kernel, the roster's ``Qcap`` for cluster-major)
+    and fold it into each row's running top-k.
+
+    Refs after the scalar-prefetch ones: q ``(1, m, d)``, q_loc
+    ``(1, m, 2)``, w_st ``(1, m, 2)``, step table ``(t/128, 128)``, emb
+    ``(1, bn, d)``, [scale ``(1, 1, bn)``], loc ``(1, 2, bn)``, ids
+    ``(1, 1, bn)``, [attrs ``(1, 3, bn)``, q_filt ``(1, m, 4)``], then
+    the outputs scores / ids ``(1, m, k)``."""
+    refs = list(refs[n_prefetch:])
+    qe_ref, ql_ref, qw_ref, tab_ref, emb_ref = refs[:5]
+    del refs[:5]
+    scale_ref = refs.pop(0) if dequant else None
+    loc_ref, ids_ref = refs.pop(0), refs.pop(0)
+    attrs_ref = qf_ref = None
+    if filtered:
+        attrs_ref, qf_ref = refs.pop(0), refs.pop(0)
+    os_ref, oi_ref = refs
+
+    @pl.when(first_step())
     def _init():
-        os_ref[...] = jnp.full_like(os_ref, NEG_INF)
-        oi_ref[...] = jnp.full_like(oi_ref, -1)
+        os_ref[...] = jnp.full(os_ref.shape, NEG_INF, jnp.float32)
+        oi_ref[...] = jnp.full(oi_ref.shape, -1, jnp.int32)
 
-    q = q_ref[...].astype(jnp.float32)            # (bm, d)
+    q = qe_ref[0].astype(jnp.float32)                         # (m, d)
     trel = jax.lax.dot_general(
-        q, ce, (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)        # (bm, bn)
-
-    # spatial: s_in = 1 - clip(dist/dist_max); srel = w_hat[floor(s_in*t)]
-    dloc = loc_ref[...][:, None, :] - cl_ref[...]  # (bm, bn, 2)
-    dist = jnp.sqrt(jnp.sum(dloc * dloc, axis=-1))
-    s_in = 1.0 - jnp.clip(dist / dist_max, 0.0, 1.0)
-    idx = jnp.clip((s_in * t).astype(jnp.int32), 0, t - 1)
-    srel = jnp.take(wh_ref[...], idx)              # (bm, bn) O(1) lookup
-
-    w = w_ref[...].astype(jnp.float32)             # (bm, 2)
-    st = w[:, :1] * trel + w[:, 1:2] * srel
-    ids = ci_ref[...]                              # (bm, bn) object ids
-    st = jnp.where(ids >= 0, st, NEG_INF)          # mask buffer padding
-
-    # local candidate positions within the full N axis
-    local = j * block_n + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-
-    # merge with the running top-k held in the revisited output block
-    cat_s = jnp.concatenate([os_ref[...], st], axis=1)       # (bm, k+bn)
-    cat_i = jnp.concatenate([oi_ref[...], local], axis=1)
-    vals, pos = jax.lax.top_k(cat_s, k)
-    os_ref[...] = vals
-    oi_ref[...] = jnp.take_along_axis(cat_i, pos, axis=1)
-
-
-def _kernel(q_ref, loc_ref, w_ref, wh_ref, ce_ref, cl_ref, ci_ref,
-            os_ref, oi_ref, **kw):
-    # f32/bf16 tile: the astype is the whole upcast, no scales stream
-    _gather_body(q_ref, loc_ref, w_ref, wh_ref,
-                 ce_ref[...].astype(jnp.float32),
-                 cl_ref, ci_ref, os_ref, oi_ref, **kw)
-
-
-def _kernel_dequant(q_ref, loc_ref, w_ref, wh_ref, ce_ref, cs_ref, cl_ref,
-                    ci_ref, os_ref, oi_ref, **kw):
-    # int8 tile: upcast + per-row scale in VMEM, then the same MXU matmul
-    ce = ce_ref[...].astype(jnp.float32) * cs_ref[...][..., None]
-    _gather_body(q_ref, loc_ref, w_ref, wh_ref, ce,
-                 cl_ref, ci_ref, os_ref, oi_ref, **kw)
-
-
-def fused_topk_score(q_emb, q_loc, w_st, cand_emb, cand_loc, cand_ids,
-                     w_hat, *, k: int, dist_max: float,
-                     block_m: int = 8, block_n: int = 512,
-                     cand_scale=None, interpret: bool = True):
-    """Returns (scores (B, k) f32, local_idx (B, k) i32).
-
-    q_emb (B, d); q_loc (B, 2); w_st (B, 2); cand_emb (B, N, d) in f32,
-    bf16, or int8; cand_loc (B, N, 2); cand_ids (B, N) int32 (-1 pad);
-    w_hat (t,) f32; cand_scale (B, N) f32 per-row dequant scales
-    (required for int8 candidates, omitted otherwise — when given, the
-    tile is dequantized in VMEM before scoring).
-    """
-    b, n, d = cand_emb.shape
-    t = w_hat.shape[0]
-    # both tile sizes clamp to the largest exact divisor — an odd batch
-    # (b % block_m != 0) must never crash the serve path
-    block_m = _largest_divisor_tile(b, block_m)
-    block_n = _largest_divisor_tile(n, block_n)
-    grid = (b // block_m, n // block_n)
-
-    dequant = cand_scale is not None
-    kern = functools.partial(_kernel_dequant if dequant else _kernel,
-                             k=k, t=t, dist_max=float(dist_max),
-                             block_n=block_n)
-    emb_specs = [pl.BlockSpec((block_m, block_n, d), lambda i, j: (i, j, 0))]
-    emb_args = [cand_emb]
+        q, emb_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)                  # (m, bn)
     if dequant:
-        emb_specs.append(pl.BlockSpec((block_m, block_n),
-                                      lambda i, j: (i, j)))
-        emb_args.append(cand_scale)
-    out_shape = [
-        jax.ShapeDtypeStruct((b, k), jnp.float32),
-        jax.ShapeDtypeStruct((b, k), jnp.int32),
-    ]
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, d), lambda i, j: (i, 0)),       # q_emb
-            pl.BlockSpec((block_m, 2), lambda i, j: (i, 0)),       # q_loc
-            pl.BlockSpec((block_m, 2), lambda i, j: (i, 0)),       # w_st
-            pl.BlockSpec((t,), lambda i, j: (0,)),                 # w_hat
-            *emb_specs,                                # cand_emb [, scale]
-            pl.BlockSpec((block_m, block_n, 2), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),       # scores
-            pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),       # idx
-        ],
-        out_shape=out_shape,
-        interpret=interpret,
-    )(q_emb, q_loc, w_st, w_hat, *emb_args, cand_loc, cand_ids)
+        trel = trel * scale_ref[0]                            # per-row scale
 
-
-# ---------------------------------------------------------------------------
-# Gather-free variant: scalar-prefetched routing into resident buffers
-# ---------------------------------------------------------------------------
-
-
-def _routed_body(q_ref, loc_ref, w_ref, wh_ref, ce, bl_ref, bi_ref,
-                 os_ref, oi_ref, *, k: int, t: int, dist_max: float,
-                 pred=None):
-    """Score one routed (block_n, d) resident tile (``ce`` already f32,
-    dequantized by the caller) against its query's running top-k.
-    ``pred`` is the optional (1, block_n) filter mask evaluated by the
-    filtered wrappers — failing rows take the padding semantics."""
-    r = pl.program_id(1)
-    j = pl.program_id(2)
-
-    @pl.when((r == 0) & (j == 0))
-    def _init():
-        os_ref[...] = jnp.full_like(os_ref, NEG_INF)
-        oi_ref[...] = jnp.full_like(oi_ref, -1)
-
-    q = q_ref[...].astype(jnp.float32)              # (1, d)
-    trel = jax.lax.dot_general(
-        q, ce, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (1, bn)
-
-    dloc = loc_ref[...][:, None, :] - bl_ref[...]    # (1, bn, 2)
-    dist = jnp.sqrt(jnp.sum(dloc * dloc, axis=-1))   # (1, bn)
+    ql = ql_ref[0].astype(jnp.float32)                        # (m, 2)
+    ol = loc_ref[0]                                           # (2, bn)
+    dx = ql[:, 0:1] - ol[0:1, :]
+    dy = ql[:, 1:2] - ol[1:2, :]
+    dist = jnp.sqrt(dx * dx + dy * dy)                        # (m, bn)
     s_in = 1.0 - jnp.clip(dist / dist_max, 0.0, 1.0)
     idx = jnp.clip((s_in * t).astype(jnp.int32), 0, t - 1)
-    srel = jnp.take(wh_ref[...], idx)                # (1, bn)
+    srel = _step_lookup(tab_ref[...], idx)
 
-    w = w_ref[...].astype(jnp.float32)               # (1, 2)
-    st = w[:, :1] * trel + w[:, 1:2] * srel
-    ids = bi_ref[...]                                # (1, bn) object ids
-    valid = ids >= 0                                 # mask buffer padding
-    if pred is not None:
-        valid = valid & pred                         # ...and filtered rows
-        ids = jnp.where(valid, ids, -1)
+    w = qw_ref[0].astype(jnp.float32)                         # (m, 2)
+    st = w[:, 0:1] * trel + w[:, 1:2] * srel
+    ids = ids_ref[0]                                          # (1, bn)
+    valid = ids >= 0                                          # buffer pad
+    if filtered:
+        valid = valid & _predicate(attrs_ref[0], qf_ref[0])   # (m, bn)
     st = jnp.where(valid, st, NEG_INF)
+    ids = jnp.where(valid, ids, -1)
+    ids = jnp.broadcast_to(ids, st.shape)
 
-    # merge with the running top-k held in the revisited output block;
-    # carrying OBJECT ids (not positions) makes cr-merge order-free
-    cat_s = jnp.concatenate([os_ref[...], st], axis=1)   # (1, k+bn)
-    cat_i = jnp.concatenate([oi_ref[...], ids], axis=1)
-    vals, pos = jax.lax.top_k(cat_s, k)
-    os_ref[...] = vals
-    oi_ref[...] = jnp.take_along_axis(cat_i, pos, axis=1)
+    s, i = _merge_topk(os_ref[0], oi_ref[0], st, ids, k)
+    os_ref[0] = s
+    oi_ref[0] = i
 
 
-def _routed_kernel(tc_ref, q_ref, loc_ref, w_ref, wh_ref,
-                   be_ref, bl_ref, bi_ref, os_ref, oi_ref, **kw):
-    _routed_body(q_ref, loc_ref, w_ref, wh_ref,
-                 be_ref[...][0].astype(jnp.float32),
-                 bl_ref, bi_ref, os_ref, oi_ref, **kw)
+def _buffer_operands(buf_emb, buf_loc, buf_ids, buf_scale, buf_attrs, *,
+                     block_n: int, cluster_of, who: str):
+    """BlockSpecs + operands for the resident buffers, blocked by tile
+    ``j`` of the cluster ``cluster_of(*grid_idx_and_prefetch)`` picks."""
+    c, cap, d = buf_emb.shape
+    bn = _scan_tile(cap, block_n, who=who)
 
+    def lane(rows):
+        return pl.BlockSpec((1, rows, bn),
+                            lambda *a: (cluster_of(*a), 0, a[-2]))
 
-def _routed_kernel_dequant(tc_ref, q_ref, loc_ref, w_ref, wh_ref,
-                           be_ref, bs_ref, bl_ref, bi_ref, os_ref, oi_ref,
-                           **kw):
-    # int8 resident tile → upcast + per-row scale in VMEM; only the
-    # compressed bytes (plus a (block_n,) f32 scale strip) crossed HBM
-    ce = be_ref[...][0].astype(jnp.float32) * bs_ref[...][0][:, None]
-    _routed_body(q_ref, loc_ref, w_ref, wh_ref, ce,
-                 bl_ref, bi_ref, os_ref, oi_ref, **kw)
-
-
-def _routed_kernel_filtered(tc_ref, q_ref, loc_ref, w_ref, wh_ref,
-                            be_ref, bl_ref, bi_ref, ba_ref, qf_ref,
-                            os_ref, oi_ref, **kw):
-    # predicate evaluated in VMEM right beside the upcast: the attribute
-    # strip rode the same DMA wave as the tile it guards
-    pred = _predicate_tile(ba_ref[...][0], qf_ref[...])
-    _routed_body(q_ref, loc_ref, w_ref, wh_ref,
-                 be_ref[...][0].astype(jnp.float32),
-                 bl_ref, bi_ref, os_ref, oi_ref, pred=pred, **kw)
-
-
-def _routed_kernel_dequant_filtered(tc_ref, q_ref, loc_ref, w_ref, wh_ref,
-                                    be_ref, bs_ref, bl_ref, bi_ref, ba_ref,
-                                    qf_ref, os_ref, oi_ref, **kw):
-    pred = _predicate_tile(ba_ref[...][0], qf_ref[...])
-    ce = be_ref[...][0].astype(jnp.float32) * bs_ref[...][0][:, None]
-    _routed_body(q_ref, loc_ref, w_ref, wh_ref, ce,
-                 bl_ref, bi_ref, os_ref, oi_ref, pred=pred, **kw)
+    specs = [pl.BlockSpec((1, bn, d), lambda *a: (cluster_of(*a), a[-2], 0))]
+    args = [buf_emb]
+    if buf_scale is not None:
+        specs.append(lane(1))
+        args.append(buf_scale.astype(jnp.float32).reshape(c, 1, cap))
+    specs += [lane(2), lane(1)]
+    args += [jnp.swapaxes(buf_loc.astype(jnp.float32), 1, 2),
+             buf_ids.astype(jnp.int32).reshape(c, 1, cap)]
+    if buf_attrs is not None:
+        specs.append(lane(3))
+        args.append(jnp.swapaxes(buf_attrs.astype(jnp.int32), 1, 2))
+    return specs, args, bn
 
 
 def fused_topk_score_routed(q_emb, q_loc, w_st, top_c, buf_emb, buf_loc,
                             buf_ids, w_hat, *, k: int, dist_max: float,
-                            block_n: int = 512, buf_scale=None,
-                            buf_attrs=None, q_filt=None,
-                            interpret: bool = True):
+                            interpret: bool, block_n: int = 512,
+                            buf_scale=None, buf_attrs=None, q_filt=None):
     """Gather-free fused score + top-k over routed cluster buffers.
 
     q_emb (B, d); q_loc (B, 2); w_st (B, 2); top_c (B, cr) int32 routed
     cluster ids (scalar-prefetched); buf_emb (c, cap, d) in f32, bf16,
     or int8; buf_loc (c, cap, 2); buf_ids (c, cap) int32 (-1 pad);
     w_hat (t,) f32; buf_scale (c, cap) f32 per-row dequant scales
-    (required for int8 buffers, omitted otherwise — when given, each
-    resident tile is dequantized in VMEM before scoring).
+    (required for int8 buffers, omitted otherwise).
 
     Filtered search: pass BOTH ``buf_attrs (c, cap, 3)`` int32 object
     attributes and ``q_filt (B, 4)`` int32 compiled filter rows
     (core/filters.py) to mask failing candidates to the padding
-    semantics (NEG_INF score, id -1) in VMEM. Omitting both streams zero
-    extra bytes — the unfiltered plan is unchanged.
+    semantics (NEG_INF score, id -1) in VMEM.
 
     Returns (scores (B, k) f32, ids (B, k) i32 **global object ids**,
-    -1 where fewer than k valid candidates exist). The ``(B, cr·cap, d)``
-    candidate copy of the gather path never materializes: grid step
-    ``(b, r, j)`` streams tile ``j`` of resident cluster ``top_c[b, r]``
-    and the cr routed lists fold into one running top-k in VMEM.
+    -1 where fewer than k valid candidates exist). Grid ``(B, cr,
+    cap/block_n)``: step ``(b, r, j)`` streams tile ``j`` of resident
+    cluster ``top_c[b, r]``, and the cr routed lists fold into one
+    running top-k in VMEM. ``interpret`` runs the Pallas interpreter
+    (off the chip) instead of compiling with Mosaic.
     """
     b, d = q_emb.shape
-    c, cap, _ = buf_emb.shape
     cr = top_c.shape[1]
-    t = w_hat.shape[0]
-    # tile size must divide cap: take the largest divisor ≤ block_n (NOT
-    # the gcd, which collapses to tiny tiles for e.g. cap=1000/block=512)
-    requested = min(block_n, cap)
-    block_n = _largest_divisor_tile(cap, requested)
-    if block_n < max(1, requested // 4):
-        import warnings
-        warnings.warn(
-            f"fused_topk_score_routed: capacity {cap} has no divisor near "
-            f"the requested tile size ({requested}); tiles collapsed to "
-            f"{block_n} — pathological grid. Prefer a capacity with a "
-            f"large power-of-two factor (build_cluster_buffers rounds to "
-            f"multiples of 128)", stacklevel=2)
-    grid = (b, cr, cap // block_n)
-
-    dequant = buf_scale is not None
-    filtered = buf_attrs is not None
-    if filtered != (q_filt is not None):
+    if (buf_attrs is None) != (q_filt is None):
         raise ValueError("fused_topk_score_routed: pass buf_attrs and "
                          "q_filt together or not at all")
-    emb_specs = [pl.BlockSpec((1, block_n, d),
-                              lambda b_, r, j, tc: (tc[b_, r], j, 0))]
-    emb_args = [buf_emb]
-    if dequant:
-        emb_specs.append(pl.BlockSpec((1, block_n),
-                                      lambda b_, r, j, tc: (tc[b_, r], j)))
-        emb_args.append(buf_scale)
-    filt_specs, filt_args = [], []
-    if filtered:
-        filt_specs = [
-            pl.BlockSpec((1, block_n, 3),
-                         lambda b_, r, j, tc: (tc[b_, r], j, 0)),  # buf_attrs
-            pl.BlockSpec((1, 4), lambda b_, r, j, tc: (b_, 0)),    # q_filt
-        ]
-        filt_args = [buf_attrs.astype(jnp.int32), q_filt.astype(jnp.int32)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, d), lambda b_, r, j, tc: (b_, 0)),     # q_emb
-            pl.BlockSpec((1, 2), lambda b_, r, j, tc: (b_, 0)),     # q_loc
-            pl.BlockSpec((1, 2), lambda b_, r, j, tc: (b_, 0)),     # w_st
-            pl.BlockSpec((t,), lambda b_, r, j, tc: (0,)),          # w_hat
-            *emb_specs,                                 # buf_emb [, scale]
-            pl.BlockSpec((1, block_n, 2),
-                         lambda b_, r, j, tc: (tc[b_, r], j, 0)),   # buf_loc
-            pl.BlockSpec((1, block_n),
-                         lambda b_, r, j, tc: (tc[b_, r], j)),      # buf_ids
-            *filt_specs,                            # [buf_attrs, q_filt]
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda b_, r, j, tc: (b_, 0)),     # scores
-            pl.BlockSpec((1, k), lambda b_, r, j, tc: (b_, 0)),     # ids
-        ],
-    )
-    kerns = {(False, False): _routed_kernel,
-             (True, False): _routed_kernel_dequant,
-             (False, True): _routed_kernel_filtered,
-             (True, True): _routed_kernel_dequant_filtered}
-    kern = functools.partial(kerns[(dequant, filtered)],
-                             k=k, t=t, dist_max=float(dist_max))
-    out_shape = [
-        jax.ShapeDtypeStruct((b, k), jnp.float32),
-        jax.ShapeDtypeStruct((b, k), jnp.int32),
-    ]
-    return pl.pallas_call(
+    buf_specs, buf_args, bn = _buffer_operands(
+        buf_emb, buf_loc, buf_ids, buf_scale, buf_attrs, block_n=block_n,
+        cluster_of=lambda b_, r, j, tc: tc[b_ * cr + r],
+        who="fused_topk_score_routed")
+    tab = _step_table(w_hat)
+
+    def per_query(width):
+        return pl.BlockSpec((1, 1, width), lambda b_, r, j, tc: (b_, 0, 0))
+
+    in_specs = [per_query(d), per_query(2), per_query(2),
+                pl.BlockSpec(tab.shape, lambda *_: (0, 0)), *buf_specs]
+    args = [q_emb.reshape(b, 1, d), q_loc.reshape(b, 1, 2),
+            w_st.reshape(b, 1, 2), tab, *buf_args]
+    if q_filt is not None:
+        in_specs.append(per_query(4))
+        args.append(q_filt.astype(jnp.int32).reshape(b, 1, 4))
+    kern = functools.partial(
+        _scan_kernel, n_prefetch=1,
+        first_step=lambda: (pl.program_id(1) == 0) & (pl.program_id(2) == 0),
+        dequant=buf_scale is not None, filtered=q_filt is not None, k=k,
+        t=w_hat.shape[0], dist_max=float(dist_max))
+    scores, ids = pl.pallas_call(
         kern,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, cr, buf_emb.shape[1] // bn),
+            in_specs=in_specs, out_specs=[per_query(k), per_query(k)]),
+        out_shape=[jax.ShapeDtypeStruct((b, 1, k), jnp.float32),
+                   jax.ShapeDtypeStruct((b, 1, k), jnp.int32)],
         interpret=interpret,
-    )(top_c.astype(jnp.int32), q_emb, q_loc, w_st, w_hat,
-      *emb_args, buf_loc, buf_ids, *filt_args)
-
-
-# ---------------------------------------------------------------------------
-# Cluster-major variant: stream each distinct routed cluster once per batch
-# ---------------------------------------------------------------------------
-
-
-def _cluster_major_body(roster_ref, qe_ref, ql_ref, qw_ref, wh_ref, ce,
-                        bl_ref, bi_ref, os_ref, oi_ref, *, k: int, t: int,
-                        dist_max: float, n_total: int, pred=None):
-    """Score one (block_n, d) resident tile (``ce`` already f32,
-    dequantized by the caller) against the WHOLE query roster of the
-    distinct cluster owning it, and fold into each roster slot's
-    running top-k."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        os_ref[...] = jnp.full_like(os_ref, NEG_INF)
-        oi_ref[...] = jnp.full_like(oi_ref, -1)
-
-    q = qe_ref[...][0].astype(jnp.float32)           # (Qcap, d)
-    trel = jax.lax.dot_general(
-        q, ce, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)           # (Qcap, bn) one matmul
-
-    dloc = ql_ref[...][0][:, None, :] - bl_ref[...][0][None]  # (Qcap, bn, 2)
-    dist = jnp.sqrt(jnp.sum(dloc * dloc, axis=-1))    # (Qcap, bn)
-    s_in = 1.0 - jnp.clip(dist / dist_max, 0.0, 1.0)
-    idx = jnp.clip((s_in * t).astype(jnp.int32), 0, t - 1)
-    srel = jnp.take(wh_ref[...], idx)                 # (Qcap, bn)
-
-    w = qw_ref[...][0].astype(jnp.float32)            # (Qcap, 2)
-    st = w[:, :1] * trel + w[:, 1:2] * srel
-    ids = bi_ref[...][0]                              # (bn,) object ids
-    # mask buffer padding AND empty roster slots (roster pad = n_total):
-    # a pad slot's partials stay (-1, NEG_INF) so the caller's merge can
-    # scatter them anywhere harmlessly
-    live = roster_ref[i, :] < n_total                 # (Qcap,)
-    valid = live[:, None] & (ids[None, :] >= 0)       # (Qcap, bn)
-    if pred is not None:
-        valid = valid & pred                          # filtered rows too
-    st = jnp.where(valid, st, NEG_INF)
-    ids2 = jnp.where(valid, jnp.broadcast_to(ids[None, :], st.shape), -1)
-
-    # per-roster-slot running top-k in the revisited output block;
-    # carrying OBJECT ids keeps the final per-query merge order-free
-    cat_s = jnp.concatenate([os_ref[...][0], st], axis=1)   # (Qcap, k+bn)
-    cat_i = jnp.concatenate([oi_ref[...][0], ids2], axis=1)
-    vals, pos = jax.lax.top_k(cat_s, k)
-    os_ref[...] = vals[None]
-    oi_ref[...] = jnp.take_along_axis(cat_i, pos, axis=1)[None]
-
-
-def _cluster_major_kernel(u_ref, roster_ref, qe_ref, ql_ref, qw_ref, wh_ref,
-                          be_ref, bl_ref, bi_ref, os_ref, oi_ref, **kw):
-    _cluster_major_body(roster_ref, qe_ref, ql_ref, qw_ref, wh_ref,
-                        be_ref[...][0].astype(jnp.float32),
-                        bl_ref, bi_ref, os_ref, oi_ref, **kw)
-
-
-def _cluster_major_kernel_dequant(u_ref, roster_ref, qe_ref, ql_ref, qw_ref,
-                                  wh_ref, be_ref, bs_ref, bl_ref, bi_ref,
-                                  os_ref, oi_ref, **kw):
-    # int8 tile → upcast + per-row scale in VMEM ONCE per distinct
-    # cluster per batch (the query-major kernel re-dequantizes per route)
-    ce = be_ref[...][0].astype(jnp.float32) * bs_ref[...][0][:, None]
-    _cluster_major_body(roster_ref, qe_ref, ql_ref, qw_ref, wh_ref, ce,
-                        bl_ref, bi_ref, os_ref, oi_ref, **kw)
-
-
-def _cluster_major_kernel_filtered(u_ref, roster_ref, qe_ref, ql_ref, qw_ref,
-                                   wh_ref, be_ref, bl_ref, bi_ref, ba_ref,
-                                   qf_ref, os_ref, oi_ref, **kw):
-    # (Qcap, bn) predicate: the tile's attribute strip against the whole
-    # roster's compiled filters — evaluated once per distinct cluster
-    # per batch, right beside the (single) upcast
-    pred = _predicate_tile(ba_ref[...][0], qf_ref[...][0])
-    _cluster_major_body(roster_ref, qe_ref, ql_ref, qw_ref, wh_ref,
-                        be_ref[...][0].astype(jnp.float32),
-                        bl_ref, bi_ref, os_ref, oi_ref, pred=pred, **kw)
-
-
-def _cluster_major_kernel_dequant_filtered(u_ref, roster_ref, qe_ref, ql_ref,
-                                           qw_ref, wh_ref, be_ref, bs_ref,
-                                           bl_ref, bi_ref, ba_ref, qf_ref,
-                                           os_ref, oi_ref, **kw):
-    pred = _predicate_tile(ba_ref[...][0], qf_ref[...][0])
-    ce = be_ref[...][0].astype(jnp.float32) * bs_ref[...][0][:, None]
-    _cluster_major_body(roster_ref, qe_ref, ql_ref, qw_ref, wh_ref, ce,
-                        bl_ref, bi_ref, os_ref, oi_ref, pred=pred, **kw)
+    )(top_c.reshape(-1).astype(jnp.int32), *args)
+    return scores.reshape(b, k), ids.reshape(b, k)
 
 
 def fused_topk_score_cluster_major(q_emb_r, q_loc_r, w_st_r, u, roster,
                                    buf_emb, buf_loc, buf_ids, w_hat, *,
                                    k: int, dist_max: float, n_total: int,
-                                   block_n: int = 512, buf_scale=None,
-                                   buf_attrs=None, q_filt_r=None,
-                                   interpret: bool = True):
-    """Cluster-major fused score + top-k over the deduped batch plan.
+                                   interpret: bool, block_n: int = 512,
+                                   buf_scale=None, buf_attrs=None,
+                                   q_filt_r=None):
+    """Cluster-major fused score + top-k over the batch plan.
 
     Inputs are the plan of ``serving.cluster_major_plan`` plus the
-    roster-gathered query payloads: q_emb_r (u_max, Qcap, d) /
-    q_loc_r (u_max, Qcap, 2) / w_st_r (u_max, Qcap, 2) the queries of
-    each distinct cluster's roster; u (u_max,) int32 distinct routed
-    cluster ids; roster (u_max, Qcap) int32 flattened (query, route)
-    indices with ``n_total = B·cr`` marking empty slots (both ``u`` and
-    ``roster`` are scalar-prefetched); buf_emb (c, cap, d) in f32, bf16,
-    or int8; buf_loc (c, cap, 2); buf_ids (c, cap) int32 (-1 pad);
-    w_hat (t,) f32; buf_scale (c, cap) f32 per-row dequant scales
-    (required for int8 buffers, omitted otherwise).
+    roster-gathered query payloads: q_emb_r (rows, Qcap, d) / q_loc_r
+    (rows, Qcap, 2) / w_st_r (rows, Qcap, 2) the queries of each plan
+    row's roster; u (rows,) int32 the cluster each row scans
+    (scalar-prefetched); roster (rows, Qcap) int32 flattened (query,
+    route) indices with ``n_total = B·cr`` marking empty slots;
+    buf_emb (c, cap, d) in f32, bf16, or int8; buf_loc (c, cap, 2);
+    buf_ids (c, cap) int32 (-1 pad); w_hat (t,) f32; buf_scale (c, cap)
+    f32 per-row dequant scales (required for int8 buffers, omitted
+    otherwise).
 
     Filtered search: pass BOTH ``buf_attrs (c, cap, 3)`` int32 object
-    attributes and ``q_filt_r (u_max, Qcap, 4)`` int32 roster-gathered
-    compiled filter rows (blocked like the query payloads) to mask
-    failing candidates to the padding semantics in VMEM.
+    attributes and ``q_filt_r (rows, Qcap, 4)`` int32 roster-gathered
+    compiled filter rows to mask failing candidates in VMEM.
 
-    Returns partial per-roster-slot top-k lists
-    (scores (u_max, Qcap, k) f32, ids (u_max, Qcap, k) i32 global object
-    ids, (-1, NEG_INF) on empty roster slots and past-the-end). Fold
-    them per query with ``engine.merge_cluster_major(roster)`` — the
-    partial lists of a query's ``cr`` routes live at its roster slots.
+    Returns partial per-roster-slot top-k lists (scores (rows, Qcap, k)
+    f32, ids (rows, Qcap, k) i32 global object ids, (-1, NEG_INF) on
+    empty roster slots and past-the-end). Fold them per query with
+    ``engine.merge_cluster_major(roster)``.
 
-    Grid ``(u_max, cap/block_n)``: step ``(i, j)`` DMAs tile ``j`` of
-    distinct cluster ``u[i]`` — each distinct cluster's resident bytes
-    cross HBM ONCE per batch instead of once per routed query, so the
-    stream shrinks by the batch dedup factor ``B·cr/U`` (structurally
-    bounded by ``B·cr / min(B·cr, c)``). The whole roster is scored
-    against the tile in one ``(Qcap, d) × (d, block_n)`` MXU matmul; on
-    a real TPU prefer ``Qcap`` a multiple of 8 (it is the matmul's
-    sublane dim) — the default ``Qcap = B·cr`` of the engine's plans
-    satisfies this for any batch that is itself a multiple of 8.
+    Grid ``(rows, cap/block_n)``: step ``(i, j)`` DMAs tile ``j`` of
+    cluster ``u[i]`` and scores it against the row's whole roster in one
+    ``(Qcap, d) × (d, block_n)`` MXU matmul — each distinct cluster's
+    bytes cross HBM once per plan row instead of once per routed query.
+    ``Qcap`` bounds the query block held in VMEM.
     """
-    u_max, qcap, d = q_emb_r.shape
-    c, cap, _ = buf_emb.shape
-    t = w_hat.shape[0]
-    requested = min(block_n, cap)
-    block_n = _largest_divisor_tile(cap, requested)
-    if block_n < max(1, requested // 4):
-        import warnings
-        warnings.warn(
-            f"fused_topk_score_cluster_major: capacity {cap} has no "
-            f"divisor near the requested tile size ({requested}); tiles "
-            f"collapsed to {block_n} — pathological grid. Prefer a "
-            f"capacity with a large power-of-two factor "
-            f"(build_cluster_buffers rounds to multiples of 128)",
-            stacklevel=2)
-    grid = (u_max, cap // block_n)
-
-    dequant = buf_scale is not None
-    filtered = buf_attrs is not None
-    if filtered != (q_filt_r is not None):
+    rows, qcap, d = q_emb_r.shape
+    if (buf_attrs is None) != (q_filt_r is None):
         raise ValueError("fused_topk_score_cluster_major: pass buf_attrs "
                          "and q_filt_r together or not at all")
-    emb_specs = [pl.BlockSpec((1, block_n, d),
-                              lambda i, j, u_, ro: (u_[i], j, 0))]
-    emb_args = [buf_emb]
-    if dequant:
-        emb_specs.append(pl.BlockSpec((1, block_n),
-                                      lambda i, j, u_, ro: (u_[i], j)))
-        emb_args.append(buf_scale)
-    filt_specs, filt_args = [], []
-    if filtered:
-        filt_specs = [
-            pl.BlockSpec((1, block_n, 3),
-                         lambda i, j, u_, ro: (u_[i], j, 0)),      # buf_attrs
-            pl.BlockSpec((1, qcap, 4),
-                         lambda i, j, u_, ro: (i, 0, 0)),          # q_filt_r
-        ]
-        filt_args = [buf_attrs.astype(jnp.int32),
-                     q_filt_r.astype(jnp.int32)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, qcap, d), lambda i, j, u_, ro: (i, 0, 0)),
-            pl.BlockSpec((1, qcap, 2), lambda i, j, u_, ro: (i, 0, 0)),
-            pl.BlockSpec((1, qcap, 2), lambda i, j, u_, ro: (i, 0, 0)),
-            pl.BlockSpec((t,), lambda i, j, u_, ro: (0,)),          # w_hat
-            *emb_specs,                                 # buf_emb [, scale]
-            pl.BlockSpec((1, block_n, 2),
-                         lambda i, j, u_, ro: (u_[i], j, 0)),       # buf_loc
-            pl.BlockSpec((1, block_n),
-                         lambda i, j, u_, ro: (u_[i], j)),          # buf_ids
-            *filt_specs,                          # [buf_attrs, q_filt_r]
-        ],
-        out_specs=[
-            pl.BlockSpec((1, qcap, k), lambda i, j, u_, ro: (i, 0, 0)),
-            pl.BlockSpec((1, qcap, k), lambda i, j, u_, ro: (i, 0, 0)),
-        ],
-    )
-    kerns = {(False, False): _cluster_major_kernel,
-             (True, False): _cluster_major_kernel_dequant,
-             (False, True): _cluster_major_kernel_filtered,
-             (True, True): _cluster_major_kernel_dequant_filtered}
-    kern = functools.partial(kerns[(dequant, filtered)],
-                             k=k, t=t, dist_max=float(dist_max),
-                             n_total=int(n_total))
-    out_shape = [
-        jax.ShapeDtypeStruct((u_max, qcap, k), jnp.float32),
-        jax.ShapeDtypeStruct((u_max, qcap, k), jnp.int32),
-    ]
-    return pl.pallas_call(
+    buf_specs, buf_args, bn = _buffer_operands(
+        buf_emb, buf_loc, buf_ids, buf_scale, buf_attrs, block_n=block_n,
+        cluster_of=lambda i, j, u_: u_[i],
+        who="fused_topk_score_cluster_major")
+    tab = _step_table(w_hat)
+
+    def per_row(width):
+        return pl.BlockSpec((1, qcap, width), lambda i, j, u_: (i, 0, 0))
+
+    in_specs = [per_row(d), per_row(2), per_row(2),
+                pl.BlockSpec(tab.shape, lambda *_: (0, 0)), *buf_specs]
+    args = [q_emb_r, q_loc_r, w_st_r, tab, *buf_args]
+    if q_filt_r is not None:
+        in_specs.append(per_row(4))
+        args.append(q_filt_r.astype(jnp.int32))
+    kern = functools.partial(
+        _scan_kernel, n_prefetch=1,
+        first_step=lambda: pl.program_id(1) == 0,
+        dequant=buf_scale is not None, filtered=q_filt_r is not None, k=k,
+        t=w_hat.shape[0], dist_max=float(dist_max))
+    scores, ids = pl.pallas_call(
         kern,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows, buf_emb.shape[1] // bn),
+            in_specs=in_specs, out_specs=[per_row(k), per_row(k)]),
+        out_shape=[jax.ShapeDtypeStruct((rows, qcap, k), jnp.float32),
+                   jax.ShapeDtypeStruct((rows, qcap, k), jnp.int32)],
         interpret=interpret,
-    )(u.astype(jnp.int32), roster.astype(jnp.int32),
-      q_emb_r, q_loc_r, w_st_r, w_hat, *emb_args, buf_loc, buf_ids,
-      *filt_args)
+    )(u.astype(jnp.int32), *args)
+    # empty roster slots scored whatever query row 0 is: null them to
+    # the padding pair so every slot honours the contract
+    live = (roster < n_total)[..., None]
+    return (jnp.where(live, scores, NEG_INF),
+            jnp.where(live, ids, -1))

@@ -1,14 +1,12 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-``interpret=None`` auto-detects: compiled to Mosaic on a real TPU (or when
-forced via env REPRO_PALLAS_COMPILE=1), Pallas interpreter everywhere else
-(this CPU container) for correctness validation. The same rule backs
-core/engine.default_interpret so every entry point agrees.
+``interpret=None`` follows the platform: compiled with Mosaic on a TPU,
+the Pallas interpreter everywhere else (correctness only). The same rule
+backs core/engine.default_interpret so every entry point agrees.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 
@@ -21,21 +19,7 @@ from repro.kernels import (
 
 
 def _interpret_default() -> bool:
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return False
     return jax.default_backend() != "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("k", "dist_max", "block_m",
-                                             "block_n", "interpret"))
-def fused_topk_score(q_emb, q_loc, w_st, cand_emb, cand_loc, cand_ids, w_hat,
-                     *, k, dist_max, block_m=8, block_n=512, cand_scale=None,
-                     interpret=None):
-    interpret = _interpret_default() if interpret is None else interpret
-    return _fts.fused_topk_score(
-        q_emb, q_loc, w_st, cand_emb, cand_loc, cand_ids, w_hat, k=k,
-        dist_max=dist_max, block_m=block_m, block_n=block_n,
-        cand_scale=cand_scale, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "dist_max", "block_n",

@@ -47,6 +47,7 @@ from repro.core import index as index_lib
 from repro.core import pipeline as pl
 from repro.core import server as server_lib
 from repro.core.engine import resolve_cli_backend
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.data import GeoCorpus, GeoCorpusConfig
 
 
@@ -164,6 +165,7 @@ def main(argv=None):
                     help="Zipf exponent of the query workload (0 = uniform)")
     args = ap.parse_args(argv)
     backend = resolve_cli_backend(args.backend, args.use_pallas)
+    enable_compilation_cache()
 
     cfg = dataclasses.replace(
         get_config("list-dual-encoder"),

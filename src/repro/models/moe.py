@@ -28,12 +28,6 @@ import jax.numpy as jnp
 
 from repro.models import layers
 
-# jax.shard_map only exists as a top-level API on newer jax; fall back to
-# the experimental home so the production MoE path works on 0.4.x too.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def moe_init(key, d_model, spec, *, dtype=jnp.float32):
     k1, k2, k3, k4 = jax.random.split(key, 4)
@@ -244,7 +238,7 @@ def _moe_apply_shard_map(params, x, spec, mesh, *, group="seq"):
         aux = jax.tree.map(lambda a: jax.lax.pmean(a, dp), aux)
         return out.reshape(bl, sl, d).astype(xl.dtype), aux
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(ep, dp, None), P(ep, dp, None), P(ep, None, dp),
                   P(dp, None, None)),

@@ -50,13 +50,14 @@ def _mk_instance(rng, *, b, cr, c, cap, d, t=50, precision="f32",
             jnp.asarray(bs) if precision == "int8" else None)
 
 
-def _run_cluster_major_kernel(args, *, k, block_n=512, qcap=None):
+def _run_cluster_major_kernel(args, *, k, block_n=512, qcap=None,
+                              u_max=None):
     q, ql, w, top_c, be, bl, bi, wh, bs = args
     b, cr = top_c.shape
     c = be.shape[0]
     n = b * cr
     u, roster, _, _ = serving.cluster_major_plan(top_c, n_clusters=c,
-                                                 qcap=qcap)
+                                                 qcap=qcap, u_max=u_max)
     qidx = serving.roster_query_rows(roster, cr=cr, n_total=n)
     ps, pi = ops.fused_topk_score_cluster_major(
         q[qidx], ql[qidx], w[qidx], u, roster, be, bl, bi, wh,
@@ -160,17 +161,23 @@ def test_cluster_major_partial_and_empty_clusters(rng):
 
 
 def test_cluster_major_qcap_saturation_degrades_gracefully(rng):
-    """qcap below the realized demand drops (query, route) pairs — the
-    count is surfaced and the dropped pairs contribute empty partial
-    lists (never wrong results): queries keep whatever their surviving
-    routes found."""
+    """qcap below the realized demand spills pairs onto a second row of
+    the cluster, and the answers match the unbounded plan; with the rows
+    capped at one (``u_max=1``) the spilled pairs drop — the count is
+    surfaced and they contribute empty partial lists (never wrong
+    results): queries keep whatever their surviving routes found."""
     b, cr, c, cap, d, k = 8, 1, 4, 32, 16, 4
     top_c = np.zeros((b, 1), np.int32)          # all 8 routes → cluster 0
     args = _mk_instance(rng, b=b, cr=cr, c=c, cap=cap, d=d, top_c=top_c)
     _, _, _, n_dropped = serving.cluster_major_plan(
         jnp.asarray(top_c), n_clusters=c, qcap=5)
+    assert int(n_dropped) == 0
+    _assert_equivalent([_run_cluster_major_kernel(args, k=k, qcap=5),
+                        _run_cluster_major_kernel(args, k=k)])
+    _, _, _, n_dropped = serving.cluster_major_plan(
+        jnp.asarray(top_c), n_clusters=c, qcap=5, u_max=1)
     assert int(n_dropped) == 3
-    s, i = _run_cluster_major_kernel(args, k=k, qcap=5)
+    s, i = _run_cluster_major_kernel(args, k=k, qcap=5, u_max=1)
     s, i = np.asarray(s), np.asarray(i)
     # stable sort keeps the FIRST 5 (query, route) pairs; the rest answer
     # with empty lists

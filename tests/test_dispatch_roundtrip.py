@@ -132,8 +132,9 @@ def test_cluster_major_plan_roundtrip_invariants(b, cr, c, seed):
 
 def test_cluster_major_plan_single_cluster_saturation():
     """All B·cr routes land on ONE cluster: U=1, roster row 0 saturated.
-    At qcap exactly B·cr nothing drops; one below, exactly one pair
-    drops (the LAST in stable sort order) and is counted."""
+    One slot below B·cr, the LAST pair in stable sort order spills onto
+    a second row of the same cluster; only a caller-forced ``u_max`` of
+    one row drops it, and then it is counted."""
     b, cr, c = 8, 2, 4
     n = b * cr
     top_c = np.full((b, cr), 2, np.int32)
@@ -141,8 +142,13 @@ def test_cluster_major_plan_single_cluster_saturation():
     assert n_distinct == 1 and n_dropped == 0 and u[0] == 2
     assert sorted(roster[0].tolist()) == list(range(n))    # saturated
     assert (roster[1:] == n).all()
-    # exact saturation boundary: qcap = n-1 drops exactly one pair
+    # exact saturation boundary: qcap = n-1 spills exactly one pair
     u, roster, n_distinct, n_dropped = _plan(top_c, c, qcap=n - 1)
+    assert n_distinct == 1 and n_dropped == 0
+    assert sorted(roster[0].tolist()) == list(range(n - 1))
+    assert u[1] == 2 and roster[1].tolist() == [n - 1] + [n] * (n - 2)
+    u, roster, n_distinct, n_dropped = _plan(top_c, c, qcap=n - 1,
+                                             u_max=1)
     assert n_distinct == 1 and n_dropped == 1
     assert sorted(roster[0].tolist()) == list(range(n - 1))
 

@@ -206,14 +206,20 @@ def _mixed_specs(b):
 # ---------------------------------------------------------------------------
 
 
-def filtered_oracle(eng, snap, tok, msk, loc, specs, *, k, cr):
+def filtered_oracle(eng, snap, tok, msk, loc, specs, *, k, cr, batch):
     """Route with the engine's own (deterministic) prefix, then score the
     routed clusters' candidates entirely in numpy: dequant, Eq. 5 serve
-    form, predicate, top-k. Independent of every jit'd scan path."""
+    form, predicate, top-k. Independent of every jit'd scan path.
+
+    The prefix runs in the engine's own ``batch``-row chunks: the towers
+    compute in bfloat16, and XLA picks its dot strategy per batch shape,
+    so encoding all rows at once can round a query embedding differently
+    from the engine's chunked encode."""
     prefix = eng.prefix_fn(cr=cr)
-    q_emb, w, top_c = (np.asarray(x) for x in prefix(
-        snap.rel_params, snap.index_params, snap.norm,
-        jnp.asarray(tok), jnp.asarray(msk), jnp.asarray(loc)))
+    q_emb, w, top_c = engine_lib.run_batched(
+        lambda t, m, l: prefix(snap.rel_params, snap.index_params,
+                               snap.norm, t, m, l),
+        [tok, msk, loc], batch=batch)
     buf = snap.buffers
     be = np.asarray(buf["emb"]).astype(np.float32)
     if snap.meta.precision == "int8":
@@ -275,7 +281,7 @@ def test_filtered_parity_vs_oracle(fsnap, precision, backend, rng):
     ids, sc = eng.query(tok, msk, loc, k=k, cr=cr, batch=4,
                         snapshot=snap, filters=specs)
     want_i, want_s = filtered_oracle(eng, snap, tok, msk, loc, specs,
-                                     k=k, cr=cr)
+                                     k=k, cr=cr, batch=4)
     attrs = np.asarray(fsnap.buffers["attrs"])
     base_ids = np.asarray(fsnap.buffers["ids"])
     attrs_by_id = {int(i): attrs[base_ids == i][0]

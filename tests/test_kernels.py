@@ -13,17 +13,24 @@ from repro.kernels import ops, ref
     (4, 512, 128, 1000, 20),
 ])
 def test_fused_topk_score(b, n, d, t, k, rng):
+    """Every query routed to one n-object cluster: the routed kernel's
+    scores equal the per-candidate reference's, with padding ids and
+    duplicate ids in the buffer and a step table of up to 1000 entries
+    (several 128-entry lookup chunks)."""
     q = jnp.asarray(rng.normal(size=(b, d)), jnp.float32)
     ql = jnp.asarray(rng.uniform(size=(b, 2)), jnp.float32)
     w = jnp.asarray(rng.uniform(0.5, 1.5, size=(b, 2)), jnp.float32)
-    ce = jnp.asarray(rng.normal(size=(b, n, d)), jnp.float32)
-    cl = jnp.asarray(rng.uniform(size=(b, n, 2)), jnp.float32)
-    ci = jnp.asarray(rng.integers(-1, 10_000, size=(b, n)), jnp.int32)
+    be = jnp.asarray(rng.normal(size=(1, n, d)), jnp.float32)
+    bl = jnp.asarray(rng.uniform(size=(1, n, 2)), jnp.float32)
+    bi = jnp.asarray(rng.integers(-1, 10_000, size=(1, n)), jnp.int32)
+    tc = jnp.zeros((b, 1), jnp.int32)
     wh = jnp.asarray(np.cumsum(rng.uniform(0, 0.01, size=t)), jnp.float32)
-    s1, i1 = ops.fused_topk_score(q, ql, w, ce, cl, ci, wh, k=k,
-                                  dist_max=1.414, interpret=True)
-    s2, i2 = ref.fused_topk_score_ref(q, ql, w, ce, cl, ci, wh, k=k,
-                                      dist_max=1.414)
+    s1, _ = ops.fused_topk_score_routed(q, ql, w, tc, be, bl, bi, wh, k=k,
+                                        dist_max=1.414, interpret=True)
+    s2, _ = ref.fused_topk_score_ref(
+        q, ql, w, jnp.broadcast_to(be, (b, n, d)),
+        jnp.broadcast_to(bl, (b, n, 2)), jnp.broadcast_to(bi, (b, n)), wh,
+        k=k, dist_max=1.414)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
                                rtol=1e-5, atol=1e-5)
 
@@ -53,27 +60,6 @@ def test_fused_topk_score_routed(b, c, cap, d, t, k, cr, rng):
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
                                rtol=1e-5, atol=1e-5)
     assert (np.sort(np.asarray(i1)) == np.sort(np.asarray(i2))).all()
-
-
-def test_fused_topk_score_odd_batch_clamps_block_m(rng):
-    """Regression: b % block_m != 0 used to trip the kernel's grid
-    assert; block_m now clamps to the largest divisor of b, matching the
-    routed variant's block_n/cap rule."""
-    b, n, d, t, k = 7, 512, 16, 20, 5            # odd batch, block_m=8 > 7
-    q = jnp.asarray(rng.normal(size=(b, d)), jnp.float32)
-    ql = jnp.asarray(rng.uniform(size=(b, 2)), jnp.float32)
-    w = jnp.asarray(rng.uniform(0.5, 1.5, size=(b, 2)), jnp.float32)
-    ce = jnp.asarray(rng.normal(size=(b, n, d)), jnp.float32)
-    cl = jnp.asarray(rng.uniform(size=(b, n, 2)), jnp.float32)
-    ci = jnp.asarray(rng.integers(-1, 10_000, size=(b, n)), jnp.int32)
-    wh = jnp.asarray(np.cumsum(rng.uniform(0, 0.01, size=t)), jnp.float32)
-    s1, i1 = ops.fused_topk_score(q, ql, w, ce, cl, ci, wh, k=k,
-                                  dist_max=1.414, block_m=8, interpret=True)
-    s2, _ = ref.fused_topk_score_ref(q, ql, w, ce, cl, ci, wh, k=k,
-                                     dist_max=1.414)
-    assert s1.shape == (b, k)
-    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
-                               rtol=1e-5, atol=1e-5)
 
 
 def test_fused_topk_score_routed_tile_collapse_warns_but_correct(rng):
@@ -137,30 +123,6 @@ def test_fused_topk_score_routed_int8_dequant(b, c, cap, d, t, k, cr, rng):
     assert (np.sort(np.asarray(i1)) == np.sort(np.asarray(i2))).all()
 
 
-def test_fused_topk_score_int8_dequant_gather_variant(rng):
-    """The gather-path kernel's dequant variant agrees with scoring the
-    host-dequantized candidates through the f32 reference."""
-    from repro.core import index as il
-    b, n, d, t, k = 4, 512, 16, 20, 8
-    q = jnp.asarray(rng.normal(size=(b, d)), jnp.float32)
-    ql = jnp.asarray(rng.uniform(size=(b, 2)), jnp.float32)
-    w = jnp.asarray(rng.uniform(0.5, 1.5, size=(b, 2)), jnp.float32)
-    emb = rng.normal(size=(b, n, d)).astype(np.float32)
-    q_emb8, scale = il.quantize_rows(emb, "int8")
-    cl = jnp.asarray(rng.uniform(size=(b, n, 2)), jnp.float32)
-    ci = jnp.asarray(rng.integers(-1, 10_000, size=(b, n)), jnp.int32)
-    wh = jnp.asarray(np.cumsum(rng.uniform(0, 0.01, size=t)), jnp.float32)
-    s1, _ = ops.fused_topk_score(q, ql, w, jnp.asarray(q_emb8), cl, ci, wh,
-                                 k=k, dist_max=1.414,
-                                 cand_scale=jnp.asarray(scale),
-                                 interpret=True)
-    deq = jnp.asarray(il.dequantize_rows(q_emb8, scale, "int8"))
-    s2, _ = ref.fused_topk_score_ref(q, ql, w, deq, cl, ci, wh, k=k,
-                                     dist_max=1.414)
-    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
-                               rtol=1e-5, atol=1e-5)
-
-
 def test_quantize_rows_int8_bounds_error(rng):
     """Symmetric per-row scalar quantization: |emb − deq(q)| ≤ scale/2
     elementwise, padding (all-zero) rows get unit scales and stay exact."""
@@ -175,17 +137,20 @@ def test_quantize_rows_int8_bounds_error(rng):
 
 
 def test_fused_topk_masks_padding(rng):
+    """A cluster that is all padding but for k slots: only those k
+    objects can be selected."""
     b, n, d, t, k = 4, 512, 16, 20, 8
     q = jnp.asarray(rng.normal(size=(b, d)), jnp.float32)
     ql = jnp.zeros((b, 2), jnp.float32)
     w = jnp.ones((b, 2), jnp.float32)
-    ce = jnp.asarray(rng.normal(size=(b, n, d)), jnp.float32)
-    cl = jnp.zeros((b, n, 2), jnp.float32)
-    ci = jnp.full((b, n), -1, jnp.int32)          # everything is padding
-    ci = ci.at[:, :k].set(jnp.arange(k))
+    be = jnp.asarray(rng.normal(size=(1, n, d)), jnp.float32)
+    bl = jnp.zeros((1, n, 2), jnp.float32)
+    bi = jnp.full((1, n), -1, jnp.int32)          # everything is padding
+    bi = bi.at[:, :k].set(jnp.arange(k))
     wh = jnp.asarray(np.linspace(0, 1, t), jnp.float32)
-    s, i = ops.fused_topk_score(q, ql, w, ce, cl, ci, wh, k=k,
-                                dist_max=1.414, interpret=True)
+    s, i = ops.fused_topk_score_routed(q, ql, w, jnp.zeros((b, 1), jnp.int32),
+                                       be, bl, bi, wh, k=k, dist_max=1.414,
+                                       interpret=True)
     # only the k valid slots can be selected
     assert (np.asarray(i) < k).all() and (np.asarray(i) >= 0).all()
 

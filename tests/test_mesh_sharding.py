@@ -302,10 +302,14 @@ def test_sharded_persistence_with_delta(snap8, queries, tmp_path, rng):
     _need(2)
     tok, msk, loc = queries
     d = snap8.cfg.d_model
+    # the delta rows are scaled copies of the first queries' own
+    # embeddings at their locations, so they rank first by construction
+    # (random rows rank wherever the random draw puts them)
+    prefix = engine_lib.QueryEngine(snap8, backend="dense").prefix_fn(cr=1)
+    q_emb = np.asarray(prefix(snap8.rel_params, snap8.index_params,
+                              snap8.norm, tok[:4], msk[:4], loc[:4])[0])
     seg = delta_lib.DeltaSegment.empty(d, "f32")
-    seg = seg.insert(rng.normal(size=(4, d)).astype(np.float32),
-                     rng.uniform(size=(4, 2)).astype(np.float32),
-                     np.arange(9000, 9004))
+    seg = seg.insert(4.0 * q_emb, loc[:4], np.arange(9000, 9004))
     live_id = int(np.asarray(snap8.buffers["ids"]).ravel()[0])
     seg = seg.delete([live_id])
     snap_d = snap8.with_delta(seg)
@@ -455,8 +459,10 @@ def test_shard_holding_only_padding_clusters(rng):
                               norm)
     top = np.asarray(il.assign_clusters(iparams, feats, top=2))
     top = np.clip(top, 0, 1)           # clusters 2 and 3 stay EMPTY
+    # capacity n: the first hop (cluster 0 or 1) always has room, so no
+    # object ever falls back to the least-loaded (empty) clusters
     buf = il.build_cluster_buffers(top, obj_emb, obj_loc, n_clusters=4,
-                                   capacity=48)
+                                   capacity=n)
     snap = IndexSnapshot.from_parts(cfg, rel, iparams, norm, buf,
                                     dist_max=DIST_MAX)
     bi = np.asarray(snap.buffers["ids"])

@@ -12,6 +12,7 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+import jax.extend.core as jex_core
 import pytest
 
 from repro.configs import get_config
@@ -302,7 +303,6 @@ def test_resolve_backend_rules():
     assert engine.resolve_backend("pallas", interpret=True) == ("pallas",
                                                                 True)
     # auto keys on hardware, NOT the interpret flag: pallas iff on TPU
-    # (so REPRO_PALLAS_COMPILE=1 on CPU can't route auto into Mosaic)
     expect = "pallas" if jax.default_backend() == "tpu" else "dense"
     assert engine.resolve_backend("auto", interpret=True)[0] == expect
     assert engine.resolve_backend("auto", interpret=False)[0] == expect
@@ -325,9 +325,9 @@ def _subjaxprs_of(params):
     for val in params.values():
         vals = val if isinstance(val, (tuple, list)) else (val,)
         for v in vals:
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, jex_core.ClosedJaxpr):
                 yield v.jaxpr
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, jex_core.Jaxpr):
                 yield v
 
 

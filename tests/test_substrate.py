@@ -200,12 +200,11 @@ def test_error_feedback_reduces_bias():
 def test_compressed_psum_matches_mean(rng):
     """shard_map int8 psum ≈ plain mean of per-device grads."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     if jax.device_count() < 1:
         pytest.skip("no devices")
     g = jnp.asarray(rng.normal(size=(jax.device_count(), 128)), jnp.float32)
     mesh = Mesh(np.array(jax.devices()), ("d",))
-    out = shard_map(
+    out = jax.shard_map(
         lambda x: comp.compressed_psum(x[0], "d")[None],
         mesh=mesh, in_specs=P("d", None), out_specs=P("d", None))(g)
     ref = g.mean(axis=0)
