@@ -1,0 +1,6 @@
+"""Mosaic scan kernels' device time per step (ms), from the trace."""
+from chipbench import reduce
+
+
+def read(ctx):
+    return reduce.scan_device_ms(ctx, "closed_loop")

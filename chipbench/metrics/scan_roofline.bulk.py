@@ -1,0 +1,6 @@
+"""Least scan time the chip allows over the kernels' device time (%)."""
+from chipbench import reduce
+
+
+def read(ctx):
+    return reduce.scan_roofline(ctx, "closed_loop")

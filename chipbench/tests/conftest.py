@@ -1,0 +1,9 @@
+import os
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+for p in (str(ROOT), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
