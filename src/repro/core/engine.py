@@ -93,6 +93,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Callable, Optional, Sequence, Tuple
 
@@ -106,6 +107,14 @@ from repro.core import relevance
 from repro.core import spatial as sp
 
 NEG_INF = -1e30
+
+# the named device scopes (``jax.named_scope``) every plan puts its work
+# under, so a profile attributes each device op to a stage of the query
+# phase: the query tower, routing (features, router, mixing weights),
+# the scan (plan, gathers, relayouts and the kernel), and the merge of
+# partial top-k lists. An op belongs to the innermost of these in its
+# ``op_name`` path.
+DEVICE_SCOPES = ("tower", "route", "scan", "merge")
 
 BACKENDS = ("pallas", "pallas-cm", "dense", "dense-cm", "auto")
 
@@ -337,15 +346,17 @@ def merge_cluster_major(part_scores, part_ids, roster, *, b: int, cr: int,
     ids, -1 past-the-end) — the exact contract of the query-major paths.
     """
     n = b * cr
-    flat = roster.reshape(-1)
-    back_v = jnp.full((n + 1, k), NEG_INF, jnp.float32)
-    back_i = jnp.full((n + 1, k), -1, jnp.int32)
-    back_v = back_v.at[flat].set(part_scores.reshape(-1, k))
-    back_i = back_i.at[flat].set(part_ids.reshape(-1, k).astype(jnp.int32))
-    per_q_v = back_v[:n].reshape(b, cr * k)
-    per_q_i = back_i[:n].reshape(b, cr * k)
-    scores, pos = jax.lax.top_k(per_q_v, k)
-    ids = jnp.take_along_axis(per_q_i, pos, axis=1)
+    with jax.named_scope("merge"):
+        flat = roster.reshape(-1)
+        back_v = jnp.full((n + 1, k), NEG_INF, jnp.float32)
+        back_i = jnp.full((n + 1, k), -1, jnp.int32)
+        back_v = back_v.at[flat].set(part_scores.reshape(-1, k))
+        back_i = back_i.at[flat].set(
+            part_ids.reshape(-1, k).astype(jnp.int32))
+        per_q_v = back_v[:n].reshape(b, cr * k)
+        per_q_i = back_i[:n].reshape(b, cr * k)
+        scores, pos = jax.lax.top_k(per_q_v, k)
+        ids = jnp.take_along_axis(per_q_i, pos, axis=1)
     return scores, ids
 
 
@@ -403,6 +414,23 @@ def dense_cluster_major(q_emb, q_loc, w_st, top_c, buf_emb, buf_loc, buf_ids,
 # ---------------------------------------------------------------------------
 
 
+def _encode_and_route(cfg, rel_params, index_params, norm, q_tokens,
+                      q_mask, q_loc, *, cr: int,
+                      weight_mode: Optional[str] = None):
+    """The query phase's prefix, shared by every plan that routes:
+    encode (scope ``tower``), then index features, the top-``cr``
+    clusters and, given ``weight_mode``, the mixing weights (scope
+    ``route``). → (q_emb (B, d), top_c (B, cr), w (B, 2) or None)."""
+    with jax.named_scope("tower"):
+        q_emb = relevance.encode_queries(rel_params, q_tokens, q_mask, cfg)
+    with jax.named_scope("route"):
+        feats = index_lib.build_features(q_emb, q_loc, norm)
+        top_c, _ = index_lib.route_queries(index_params, feats, cr=cr)
+        w = (None if weight_mode is None else
+             relevance.st_weights(rel_params, q_emb, weight_mode=weight_mode))
+    return q_emb, top_c, w
+
+
 def _routed_topk(q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids,
                  buf_scale, w_hat, *, k: int, backend: str, interpret: bool,
                  dist_max: float, block_n: int, precision: str,
@@ -417,41 +445,42 @@ def _routed_topk(q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids,
     """
     # f32/bf16 stream no scales: the astype upcast is the whole dequant
     scale = buf_scale if precision == "int8" else None
-    if backend == "pallas":
-        from repro.kernels import fused_topk_score as fts
-        score, ids = fts.fused_topk_score_routed(
-            q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids, w_hat,
-            k=k, dist_max=dist_max, block_n=block_n, buf_scale=scale,
-            buf_attrs=buf_attrs, q_filt=q_filt, interpret=interpret)
-    elif backend == "pallas-cm":
-        # cluster-major (DESIGN.md §10): dedupe the routed clusters,
-        # stream each distinct one ONCE against its query roster
-        from repro.core import serving as serving_lib
-        from repro.kernels import fused_topk_score as fts
-        b = q_emb.shape[0]
-        cr = top_c.shape[1]
-        n = b * cr
-        qcap = min(-(-n // 8) * 8, CLUSTER_MAJOR_QCAP)
-        u, roster, _, _ = serving_lib.cluster_major_plan(
-            top_c, n_clusters=buf_emb.shape[0], qcap=qcap)
-        qidx = serving_lib.roster_query_rows(roster, cr=cr, n_total=n)
-        q_filt_r = q_filt[qidx] if q_filt is not None else None
-        ps, pi = fts.fused_topk_score_cluster_major(
-            q_emb[qidx], q_loc[qidx], w[qidx], u, roster,
-            buf_emb, buf_loc, buf_ids, w_hat, k=k, dist_max=dist_max,
-            n_total=n, block_n=block_n, buf_scale=scale,
-            buf_attrs=buf_attrs, q_filt_r=q_filt_r, interpret=interpret)
-        score, ids = merge_cluster_major(ps, pi, roster, b=b, cr=cr, k=k)
-    elif backend == "dense-cm":
-        score, ids = dense_cluster_major(
-            q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids, w_hat,
-            k=k, dist_max=dist_max, buf_scale=scale,
-            buf_attrs=buf_attrs, q_filt=q_filt)
-    else:
-        score, ids = dense_routed_topk(
-            q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids, w_hat,
-            k=k, dist_max=dist_max, buf_scale=scale,
-            buf_attrs=buf_attrs, q_filt=q_filt)
+    with jax.named_scope("scan"):
+        if backend == "pallas":
+            from repro.kernels import fused_topk_score as fts
+            score, ids = fts.fused_topk_score_routed(
+                q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids, w_hat,
+                k=k, dist_max=dist_max, block_n=block_n, buf_scale=scale,
+                buf_attrs=buf_attrs, q_filt=q_filt, interpret=interpret)
+        elif backend == "pallas-cm":
+            # cluster-major (DESIGN.md §10): dedupe the routed clusters,
+            # stream each distinct one ONCE against its query roster
+            from repro.core import serving as serving_lib
+            from repro.kernels import fused_topk_score as fts
+            b = q_emb.shape[0]
+            cr = top_c.shape[1]
+            n = b * cr
+            qcap = min(-(-n // 8) * 8, CLUSTER_MAJOR_QCAP)
+            u, roster, _, _ = serving_lib.cluster_major_plan(
+                top_c, n_clusters=buf_emb.shape[0], qcap=qcap)
+            qidx = serving_lib.roster_query_rows(roster, cr=cr, n_total=n)
+            q_filt_r = q_filt[qidx] if q_filt is not None else None
+            ps, pi = fts.fused_topk_score_cluster_major(
+                q_emb[qidx], q_loc[qidx], w[qidx], u, roster,
+                buf_emb, buf_loc, buf_ids, w_hat, k=k, dist_max=dist_max,
+                n_total=n, block_n=block_n, buf_scale=scale,
+                buf_attrs=buf_attrs, q_filt_r=q_filt_r, interpret=interpret)
+            score, ids = merge_cluster_major(ps, pi, roster, b=b, cr=cr, k=k)
+        elif backend == "dense-cm":
+            score, ids = dense_cluster_major(
+                q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids, w_hat,
+                k=k, dist_max=dist_max, buf_scale=scale,
+                buf_attrs=buf_attrs, q_filt=q_filt)
+        else:
+            score, ids = dense_routed_topk(
+                q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids, w_hat,
+                k=k, dist_max=dist_max, buf_scale=scale,
+                buf_attrs=buf_attrs, q_filt=q_filt)
     return ids, score
 
 
@@ -515,11 +544,9 @@ def make_query_fn(cfg, *, cr: int = 1, k: int = 20, backend: str = "auto",
 
     def _run(rel_params, index_params, w_hat, norm, buf_emb, buf_loc,
              buf_ids, buf_scale, q_tokens, q_mask, q_loc, buf_attrs, q_filt):
-        q_emb = relevance.encode_queries(rel_params, q_tokens, q_mask, cfg)
-        feats = index_lib.build_features(q_emb, q_loc, norm)
-        top_c, _ = index_lib.route_queries(index_params, feats, cr=cr)
-        w = relevance.st_weights(rel_params, q_emb,
-                                 weight_mode=weight_mode)          # (B, 2)
+        q_emb, top_c, w = _encode_and_route(
+            cfg, rel_params, index_params, norm, q_tokens, q_mask, q_loc,
+            cr=cr, weight_mode=weight_mode)
         return _routed_topk(q_emb, q_loc, w, top_c, buf_emb, buf_loc,
                             buf_ids, buf_scale, w_hat, k=k, backend=backend,
                             interpret=interpret, dist_max=dist_max,
@@ -552,10 +579,8 @@ def make_route_fn(cfg, *, cr: int = 1):
     benchmarks use it to measure a batch's dedup factor ``B·cr/U``
     without running the scan."""
     def route_fn(rel_params, index_params, norm, q_tokens, q_mask, q_loc):
-        q_emb = relevance.encode_queries(rel_params, q_tokens, q_mask, cfg)
-        feats = index_lib.build_features(q_emb, q_loc, norm)
-        top_c, _ = index_lib.route_queries(index_params, feats, cr=cr)
-        return top_c
+        return _encode_and_route(cfg, rel_params, index_params, norm,
+                                 q_tokens, q_mask, q_loc, cr=cr)[1]
 
     return jax.jit(route_fn)
 
@@ -578,10 +603,9 @@ def make_prefix_fn(cfg, *, cr: int = 1, weight_mode: str = "mlp"):
     mesh), so ``q_emb``/``w``/``top_c`` are bit-identical across
     placements — the first leg of the parity contract."""
     def prefix_fn(rel_params, index_params, norm, q_tokens, q_mask, q_loc):
-        q_emb = relevance.encode_queries(rel_params, q_tokens, q_mask, cfg)
-        feats = index_lib.build_features(q_emb, q_loc, norm)
-        top_c, _ = index_lib.route_queries(index_params, feats, cr=cr)
-        w = relevance.st_weights(rel_params, q_emb, weight_mode=weight_mode)
+        q_emb, top_c, w = _encode_and_route(
+            cfg, rel_params, index_params, norm, q_tokens, q_mask, q_loc,
+            cr=cr, weight_mode=weight_mode)
         return q_emb, w, top_c
 
     return jax.jit(prefix_fn)
@@ -718,28 +742,33 @@ def make_delta_scan_fn(cfg, *, k: int = 20, dist_max: float = 1.4142,
 
     def _scan(rel_params, w_hat, d_emb, d_scale, d_loc, d_ids, d_attrs,
               q_tokens, q_mask, q_loc, q_filt):
-        q_emb = relevance.encode_queries(rel_params, q_tokens, q_mask, cfg)
-        w = relevance.st_weights(rel_params, q_emb, weight_mode=weight_mode)
+        with jax.named_scope("tower"):
+            q_emb = relevance.encode_queries(rel_params, q_tokens, q_mask,
+                                             cfg)
+        with jax.named_scope("route"):
+            w = relevance.st_weights(rel_params, q_emb,
+                                     weight_mode=weight_mode)
         scale = d_scale[None] if precision == "int8" else None
-        ids_eff = d_ids[None]                               # (1, m)
-        if d_attrs is not None:
-            # failing rows take full padding semantics (id -1), the
-            # shared filtered rule of every scan in this module
-            pred = filters_lib.predicate_mask(d_attrs[None],
-                                              q_filt[:, None, :])
-            ids_eff = jnp.where(pred, ids_eff, -1)          # (B, m)
-        st = score_candidates(q_emb, q_loc, w, d_emb[None], d_loc[None],
-                              ids_eff, w_hat, dist_max=dist_max,
-                              cand_scale=scale)             # (B, m)
-        kk = min(k, d_emb.shape[0])
-        vals, pos = jax.lax.top_k(st, kk)
-        ids = jnp.take_along_axis(
-            jnp.broadcast_to(ids_eff, st.shape), pos, axis=1
-        ).astype(jnp.int32)
-        if kk < k:
-            pad = ((0, 0), (0, k - kk))
-            vals = jnp.pad(vals, pad, constant_values=NEG_INF)
-            ids = jnp.pad(ids, pad, constant_values=-1)
+        with jax.named_scope("scan"):
+            ids_eff = d_ids[None]                           # (1, m)
+            if d_attrs is not None:
+                # failing rows take full padding semantics (id -1), the
+                # shared filtered rule of every scan in this module
+                pred = filters_lib.predicate_mask(d_attrs[None],
+                                                  q_filt[:, None, :])
+                ids_eff = jnp.where(pred, ids_eff, -1)      # (B, m)
+            st = score_candidates(q_emb, q_loc, w, d_emb[None], d_loc[None],
+                                  ids_eff, w_hat, dist_max=dist_max,
+                                  cand_scale=scale)         # (B, m)
+            kk = min(k, d_emb.shape[0])
+            vals, pos = jax.lax.top_k(st, kk)
+            ids = jnp.take_along_axis(
+                jnp.broadcast_to(ids_eff, st.shape), pos, axis=1
+            ).astype(jnp.int32)
+            if kk < k:
+                pad = ((0, 0), (0, k - kk))
+                vals = jnp.pad(vals, pad, constant_values=NEG_INF)
+                ids = jnp.pad(ids, pad, constant_values=-1)
         return ids, vals
 
     if filtered:
@@ -844,27 +873,35 @@ def run_batched(fn: Callable, arrays: Sequence[np.ndarray], *, batch: int):
     ``i+1``'s work has been dispatched, so on an async backend the
     device-to-host transfer of one chunk overlaps the next chunk's
     compute instead of serializing the serving path.
+
+    Each chunk's pad, upload and launch runs under the host span
+    ``repro.dispatch``, and each chunk's copy back under ``repro.sync``
+    (profiler annotations: no cost unless a trace is being taken).
     """
     n = arrays[0].shape[0]
     assert all(a.shape[0] == n for a in arrays), [a.shape for a in arrays]
+    annotate = jax.profiler.TraceAnnotation
     outs = None
     pending = None            # chunk i's device results, not yet synced
+
+    def sync(p_res, p_rows):
+        with annotate("repro.sync"):
+            for o, r in zip(outs, p_res):
+                o.append(np.asarray(r)[:p_rows])
+
     for s in range(0, n, batch):
         e = min(s + batch, n)
-        chunk = [pad_leading(np.asarray(a[s:e]), batch) for a in arrays]
-        res = fn(*[jnp.asarray(c) for c in chunk])      # dispatch, no sync
+        with annotate("repro.dispatch"):
+            chunk = [pad_leading(np.asarray(a[s:e]), batch) for a in arrays]
+            res = fn(*[jnp.asarray(c) for c in chunk])  # dispatch, no sync
         res = res if isinstance(res, (tuple, list)) else (res,)
         if outs is None:
             outs = [[] for _ in res]
         if pending is not None:
-            p_res, p_rows = pending
-            for o, r in zip(outs, p_res):
-                o.append(np.asarray(r)[:p_rows])        # sync chunk i-1
+            sync(*pending)                              # chunk i-1
         pending = (res, e - s)
     if pending is not None:
-        p_res, p_rows = pending
-        for o, r in zip(outs, p_res):
-            o.append(np.asarray(r)[:p_rows])
+        sync(*pending)
     cat = tuple(np.concatenate(o, axis=0) for o in outs)
     return cat if len(cat) > 1 else cat[0]
 
@@ -906,6 +943,10 @@ class QueryEngine:
         self._auto_cm = backend == "auto"
         self.cm_threshold = float(cm_threshold)
         self.last_dedup_factor: Optional[float] = None
+        # encoder_passes: chunks dispatched of plans that run the query
+        # tower (query plans, the auto pick's route, the sharded prefix,
+        # delta scans) — a flush that encodes its rows once reads 1
+        self.stats = {"encoder_passes": 0}
         self.max_plans = int(max_plans)
         self._plans: "collections.OrderedDict" = collections.OrderedDict()
         self._route_plans = {}          # keyed cr: tiny, never evicted
@@ -1043,19 +1084,31 @@ class QueryEngine:
         self._plans.move_to_end(key)
         return self._plans[key]
 
+    def route_fn(self, *, cr: int):
+        """The jitted route-only plan (:func:`make_route_fn`) for ``cr``:
+        one per engine, tiny, never evicted."""
+        if cr not in self._route_plans:
+            self._route_plans[cr] = make_route_fn(self.cfg, cr=cr)
+        return self._route_plans[cr]
+
     def route(self, q_tokens, q_mask, q_loc, *, cr: int = 1,
               snapshot=None):
         """Route-only prefix: → top_c (n, cr) int32 (device array).
 
-        One cached jitted plan per ``cr`` (:func:`make_route_fn`); the
-        auto heuristic and the skew benchmarks measure dedup with it."""
+        The auto heuristic and the skew benchmarks measure dedup with
+        it (:meth:`route_fn`)."""
         snap = self._snapshot if snapshot is None else snapshot
-        if cr not in self._route_plans:
-            self._route_plans[cr] = make_route_fn(self.cfg, cr=cr)
-        return self._route_plans[cr](
+        return self.route_fn(cr=cr)(
             snap.rel_params, snap.index_params, snap.norm,
             jnp.asarray(q_tokens), jnp.asarray(q_mask), jnp.asarray(q_loc))
 
+    def _run_encoding(self, fn, arrays, *, batch: int):
+        """:func:`run_batched` over a plan that runs the query tower,
+        counting one encoder pass per chunk (``stats``)."""
+        self.stats["encoder_passes"] += -(-np.shape(arrays[0])[0] // batch)
+        return run_batched(fn, arrays, batch=batch)
+
+    @functools.partial(jax.profiler.annotate_function, name="repro.pick")
     def pick_backend(self, q_tokens, q_mask, q_loc, *, cr: int, batch: int,
                      snapshot=None, base: Optional[str] = None) -> str:
         """Resolve the per-batch backend for an auto request (DESIGN.md
@@ -1086,11 +1139,11 @@ class QueryEngine:
             # shape: route_fn then compiles once per (batch, cr) — a
             # serving flush of any fill level reuses it instead of
             # retracing the encoder inside the latency-critical flush
-            tok = pad_leading(np.asarray(q_tokens[:eff]), batch)
-            msk = pad_leading(np.asarray(q_mask[:eff]), batch)
-            loc = pad_leading(np.asarray(q_loc[:eff]), batch)
-            top_c = np.asarray(self.route(tok, msk, loc, cr=cr,
-                                          snapshot=snap))[:eff]
+            route = self.route_fn(cr=cr)
+            top_c = self._run_encoding(
+                lambda t, m, l: route(snap.rel_params, snap.index_params,
+                                      snap.norm, t, m, l),
+                [q_tokens[:eff], q_mask[:eff], q_loc[:eff]], batch=batch)
             dedup = (eff * cr) / max(len(np.unique(top_c)), 1)
         self.last_dedup_factor = float(dedup)
         return cluster_major_variant(base, dedup,
@@ -1367,7 +1420,7 @@ class QueryEngine:
         arrays = [q_tokens, q_mask, q_loc]
         if filtered:
             arrays.append(fvals)
-        out = run_batched(chunk_fn, arrays, batch=batch)
+        out = self._run_encoding(chunk_fn, arrays, batch=batch)
         self.last_coverage = (coverage[0] / coverage[1]
                               if coverage[1] else 1.0)
         self.last_down_shards = tuple(sorted(down_seen))
@@ -1411,15 +1464,16 @@ class QueryEngine:
             attrs = np.zeros((m_pad, N_ATTRS), np.int32)
             attrs[:m] = arrs["attrs"]
             da = jnp.asarray(attrs)
-            return run_batched(
+            return self._run_encoding(
                 lambda t, mk, l, f: fn(snap.rel_params, w_hat, de, ds, dl,
                                        di, da, t, mk, l, f),
                 [q_tokens, q_mask, q_loc, fvals], batch=batch)
-        return run_batched(
+        return self._run_encoding(
             lambda t, mk, l: fn(snap.rel_params, w_hat, de, ds, dl, di,
                                 t, mk, l),
             [q_tokens, q_mask, q_loc], batch=batch)
 
+    @functools.partial(jax.profiler.annotate_function, name="repro.query")
     def query(self, q_tokens, q_mask, q_loc, *, k: int = 20, cr: int = 1,
               batch: int = 256, backend: Optional[str] = None,
               snapshot=None, filters=None):
@@ -1497,14 +1551,14 @@ class QueryEngine:
                                filtered=filtered)
             w_hat = snap.w_hat          # once per call, not per chunk
             if filtered:
-                ids, scores = run_batched(
+                ids, scores = self._run_encoding(
                     lambda t, m, l, f: fn(
                         snap.rel_params, snap.index_params, w_hat,
                         snap.norm, buf["emb"], buf["loc"], buf["ids"],
                         buf["scale"], buf["attrs"], t, m, l, f),
                     [q_tokens, q_mask, q_loc, fvals], batch=batch)
             else:
-                ids, scores = run_batched(
+                ids, scores = self._run_encoding(
                     lambda t, m, l: fn(snap.rel_params, snap.index_params,
                                        w_hat, snap.norm, buf["emb"],
                                        buf["loc"], buf["ids"],
@@ -1512,11 +1566,11 @@ class QueryEngine:
                     [q_tokens, q_mask, q_loc], batch=batch)
         if not use_delta:
             return ids, scores
-        d_ids = d_scores = None
-        if delta.n_rows:
-            d_ids, d_scores = self._scan_delta(snap, q_tokens, q_mask,
-                                               q_loc, k=k, batch=batch,
-                                               fvals=fvals,
-                                               filtered=filtered)
-        return merge_delta(ids, scores, d_ids, d_scores,
-                           tombstones=delta.tombstone_array(), k=k)
+        with jax.profiler.TraceAnnotation("repro.delta"):
+            d_ids = d_scores = None
+            if delta.n_rows:
+                d_ids, d_scores = self._scan_delta(
+                    snap, q_tokens, q_mask, q_loc, k=k, batch=batch,
+                    fvals=fvals, filtered=filtered)
+            return merge_delta(ids, scores, d_ids, d_scores,
+                               tombstones=delta.tombstone_array(), k=k)

@@ -23,7 +23,6 @@ the artifact ``repro.api`` saves, loads, and serves.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Optional, Tuple
 
 import numpy as np
@@ -338,11 +337,8 @@ class ListRetriever:
         eng = self.engine()
         tokens, mask = self.corpus.query_tokens(query_ids)
         q_loc = self.corpus.q_loc[query_ids].astype(np.float32)
-        t0 = time.perf_counter()
-        ids, sc = eng.query(tokens, mask, q_loc, k=k, cr=cr, batch=batch,
-                            backend=backend)
-        self.last_query_seconds = time.perf_counter() - t0
-        return ids, sc
+        return eng.query(tokens, mask, q_loc, k=k, cr=cr, batch=batch,
+                         backend=backend)
 
     # --- brute force (LIST-R over the whole corpus) -------------------------
 
@@ -362,11 +358,7 @@ class ListRetriever:
             sc, ids = jax.lax.top_k(st, k)
             return ids, sc
 
-        t0 = time.perf_counter()
-        ids, sc = engine_lib.run_batched(score_top, [q_emb, q_loc],
-                                         batch=batch)
-        self.last_query_seconds = time.perf_counter() - t0
-        return ids, sc
+        return engine_lib.run_batched(score_top, [q_emb, q_loc], batch=batch)
 
     # --- embedding accessor for baselines -----------------------------------
 

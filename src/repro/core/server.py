@@ -81,6 +81,7 @@ import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import delta as delta_lib
@@ -206,6 +207,7 @@ class ServerConfig:
 
 
 LATENCY_WINDOW = 65536       # sliding window of most-recent request latencies
+WAITS = ("admit", "queue", "flush", "resume")   # ServerStats.wait_s keys
 
 
 @dataclasses.dataclass
@@ -222,6 +224,7 @@ class ServerStats:
     coalesced: int = 0
     engine_batches: int = 0
     engine_queries: int = 0            # real (unpadded) rows sent on-device
+    encoder_passes: int = 0            # query-tower chunks those batches ran
     flushes: Dict[str, int] = dataclasses.field(
         default_factory=lambda: {"size": 0, "deadline": 0, "drain": 0})
     invalidations: int = 0
@@ -232,6 +235,16 @@ class ServerStats:
     compile_seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
     latencies_s: "collections.deque" = dataclasses.field(
         default_factory=lambda: collections.deque(maxlen=LATENCY_WINDOW))
+    # where each flushed request's latency went, summed in seconds:
+    # admit = t_arrival → enqueue (only for requests given a t_arrival,
+    # counted in ``admitted``), queue = enqueue → start of its flush,
+    # flush = that start → its set_result, resume = set_result → submit
+    # returning (each counted in ``waited``). Per request they add up to
+    # the latency ``latencies_s`` records.
+    wait_s: Dict[str, float] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(WAITS, 0.0))
+    waited: int = 0
+    admitted: int = 0
     # resilience counters (DESIGN.md §14)
     shed: Dict[str, int] = dataclasses.field(
         default_factory=lambda: {"expired": 0, "queue_full": 0,
@@ -332,7 +345,7 @@ def near_key(tokens: np.ndarray, mask: np.ndarray, loc: np.ndarray,
 
 class _Pending:
     __slots__ = ("tokens", "mask", "loc", "filt", "ekey", "ikey", "nkey",
-                 "future", "t_deadline")
+                 "future", "t_deadline", "t_enqueue", "t_flush", "t_result")
 
     def __init__(self, tokens, mask, loc, filt, ekey, ikey, nkey, future,
                  t_deadline=None):
@@ -341,6 +354,9 @@ class _Pending:
         self.ekey, self.ikey = ekey, ikey
         self.nkey, self.future = nkey, future
         self.t_deadline = t_deadline     # perf_counter stamp; None = none
+        # perf_counter stamps of the request's waits (ServerStats.wait_s)
+        self.t_enqueue = time.perf_counter()
+        self.t_flush = self.t_result = 0.0
 
 
 class StreamingServer:
@@ -844,15 +860,25 @@ class StreamingServer:
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
         self._inflight[ikey] = fut
-        self._pending.append(_Pending(tokens, mask, loc, filters, ekey,
-                                      ikey, nkey, fut, t_deadline))
+        p = _Pending(tokens, mask, loc, filters, ekey, ikey, nkey, fut,
+                     t_deadline)
+        self._pending.append(p)
         if len(self._pending) >= self.cfg.batch_size:
             self._flush("size")
         elif self._timer is None:
             self._timer = loop.call_later(self.cfg.max_delay_ms / 1e3,
                                           self._flush, "deadline")
         res = await fut
-        self.stats.latencies_s.append(time.perf_counter() - t0)
+        t_done = time.perf_counter()
+        s = self.stats
+        s.latencies_s.append(t_done - t0)
+        if t_arrival is not None:
+            s.wait_s["admit"] += p.t_enqueue - t0
+            s.admitted += 1
+        s.wait_s["queue"] += p.t_flush - p.t_enqueue
+        s.wait_s["flush"] += p.t_result - p.t_flush
+        s.wait_s["resume"] += t_done - p.t_result
+        s.waited += 1
         return res
 
     def flush_now(self):
@@ -883,10 +909,13 @@ class StreamingServer:
                     f"request shed at flush: waited past its "
                     f"{self.cfg.request_timeout_ms}ms deadline"))
             else:
+                p.t_flush = now
                 live.append(p)
         if not live:
             return
-        self._flush_group(live, reason, 0)
+        with jax.profiler.TraceAnnotation(
+                "repro.flush", seq=self.stats.engine_batches, rows=len(live)):
+            self._flush_group(live, reason, 0)
 
     def _flush_group(self, pending: List[_Pending], reason: str,
                      depth: int):
@@ -899,14 +928,15 @@ class StreamingServer:
         (bounded, doubling per bisection level) and retries as two
         halves, so co-batched healthy requests still resolve and a
         transient engine error costs retries, not a dropped batch."""
-        tok = np.stack([p.tokens for p in pending])
-        msk = np.stack([p.mask for p in pending])
-        loc = np.stack([p.loc for p in pending])
-        # per-row filters: a mixed-tenant micro-batch compiles to ONE
-        # filtered plan (sentinel no-op rows, core/filters.py); an
-        # all-unfiltered batch collapses to the unfiltered program
-        filts = ([p.filt for p in pending]
-                 if any(p.filt is not None for p in pending) else None)
+        with jax.profiler.TraceAnnotation("repro.assemble"):
+            tok = np.stack([p.tokens for p in pending])
+            msk = np.stack([p.mask for p in pending])
+            loc = np.stack([p.loc for p in pending])
+            # per-row filters: a mixed-tenant micro-batch compiles to ONE
+            # filtered plan (sentinel no-op rows, core/filters.py); an
+            # all-unfiltered batch collapses to the unfiltered program
+            filts = ([p.filt for p in pending]
+                     if any(p.filt is not None for p in pending) else None)
         # pin the snapshot for the WHOLE flush: every row of this batch
         # scores one consistent index generation even if a publish lands
         # while the engine call is executing, and the results are cached
@@ -954,16 +984,18 @@ class StreamingServer:
             self.stats.min_coverage = coverage
         if coverage < 1.0:
             self.stats.degraded_flushes += 1
-        for i, p in enumerate(pending):
-            res = (ids[i].copy(), scores[i].copy())
-            for arr in res:              # shared with the cache + every
-                arr.setflags(write=False)  # waiter: freeze, don't trust
-            self._exact.put((ver, dsig_served, p.ekey), res)
-            if p.nkey is not None:
-                self._near.put((ver, dsig_served, p.nkey), res)
-            self._inflight.pop(p.ikey, None)
-            if not p.future.done():
-                p.future.set_result(res)
+        with jax.profiler.TraceAnnotation("repro.resolve"):
+            for i, p in enumerate(pending):
+                res = (ids[i].copy(), scores[i].copy())
+                for arr in res:              # shared with the cache + every
+                    arr.setflags(write=False)  # waiter: freeze, don't trust
+                self._exact.put((ver, dsig_served, p.ekey), res)
+                if p.nkey is not None:
+                    self._near.put((ver, dsig_served, p.nkey), res)
+                self._inflight.pop(p.ikey, None)
+                if not p.future.done():
+                    p.t_result = time.perf_counter()
+                    p.future.set_result(res)
 
     def _backoff_ms(self, depth: int) -> float:
         """One bisection-retry sleep: doubling in ``depth``, capped at
@@ -1017,6 +1049,7 @@ class StreamingServer:
         if self._breaker_open and fallback is not None:
             backend = fallback
         t0 = time.perf_counter()
+        passes0 = self.engine.stats["encoder_passes"]
         try:
             faults_lib.fire("flush.slow")        # callback sleeps
             faults_lib.fire("flush.engine")      # armed → raises in-place
@@ -1035,6 +1068,8 @@ class StreamingServer:
                 self.stats.breaker_trips += 1
             raise
         dt = time.perf_counter() - t0
+        self.stats.encoder_passes += (self.engine.stats["encoder_passes"]
+                                      - passes0)
         self._flush_monitor.record("flush", dt)
         if self._flush_monitor.slow("flush"):
             self.stats.slow_flushes += 1
@@ -1088,6 +1123,9 @@ class StreamingServer:
         s = self.stats
         n = max(s.n_requests, 1)
         filled = s.engine_batches * self.cfg.batch_size
+        waits_ms = {f"{w}_wait_ms": 1e3 * s.wait_s[w]
+                    / max(s.admitted if w == "admit" else s.waited, 1)
+                    for w in WAITS}
         out = {
             "requests": s.n_requests,
             # split cache economics (DESIGN.md §7): raw counts beside the
@@ -1102,7 +1140,11 @@ class StreamingServer:
             "engine_batches": s.engine_batches,
             "engine_queries": s.engine_queries,
             "batch_fill": s.engine_queries / filled if filled else 0.0,
+            "encoder_passes_per_flush": (s.encoder_passes / s.engine_batches
+                                         if s.engine_batches else 0.0),
             "latency_ms": latency_percentiles(s.latencies_s),
+            # mean waits of a flushed request (ServerStats.wait_s)
+            **waits_ms,
             "flushes": dict(s.flushes),
             "invalidations": s.invalidations,
             "compile_seconds": dict(s.compile_seconds),

@@ -253,15 +253,18 @@ def _buffer_operands(buf_emb, buf_loc, buf_ids, buf_scale, buf_attrs, *,
 
     specs = [pl.BlockSpec((1, bn, d), lambda *a: (cluster_of(*a), a[-2], 0))]
     args = [buf_emb]
-    if buf_scale is not None:
-        specs.append(lane(1))
-        args.append(buf_scale.astype(jnp.float32).reshape(c, 1, cap))
-    specs += [lane(2), lane(1)]
-    args += [jnp.swapaxes(buf_loc.astype(jnp.float32), 1, 2),
-             buf_ids.astype(jnp.int32).reshape(c, 1, cap)]
-    if buf_attrs is not None:
-        specs.append(lane(3))
-        args.append(jnp.swapaxes(buf_attrs.astype(jnp.int32), 1, 2))
+    # the lane-major copies of the side buffers, made on every call:
+    # named so a profile shows what they cost
+    with jax.named_scope("relayout"):
+        if buf_scale is not None:
+            specs.append(lane(1))
+            args.append(buf_scale.astype(jnp.float32).reshape(c, 1, cap))
+        specs += [lane(2), lane(1)]
+        args += [jnp.swapaxes(buf_loc.astype(jnp.float32), 1, 2),
+                 buf_ids.astype(jnp.int32).reshape(c, 1, cap)]
+        if buf_attrs is not None:
+            specs.append(lane(3))
+            args.append(jnp.swapaxes(buf_attrs.astype(jnp.int32), 1, 2))
     return specs, args, bn
 
 
