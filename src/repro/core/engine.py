@@ -434,24 +434,33 @@ def _encode_and_route(cfg, rel_params, index_params, norm, q_tokens,
 def _routed_topk(q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids,
                  buf_scale, w_hat, *, k: int, backend: str, interpret: bool,
                  dist_max: float, block_n: int, precision: str,
-                 buf_attrs=None, q_filt=None):
+                 buf_attrs=None, q_filt=None, n_valid=None):
     """Backend dispatch for the routed scan: score the ``top_c``-routed
     clusters of an explicit buffer set and keep the top ``k`` — the body
     shared by :func:`make_query_fn` (inline, after encode+route) and
     :func:`make_shard_topk_fn` (per shard, routes pre-localized).
     ``backend`` must be resolved (never "auto"). ``buf_attrs``/``q_filt``
     (pass both or neither) engage the filtered variants (DESIGN.md §13).
-    Returns (ids, scores).
+    ``n_valid`` (int32 scalar, may be traced; default: all) counts the
+    batch's leading rows that are queries: the kernels stream nothing
+    for the padding rows after them, whose answers are padding pairs.
+    Returns (ids, scores, tiles): ``tiles`` int32 ``(2,)`` counts the
+    kernel grid's tiles that were streamed, and all its tiles (both 0
+    on the dense backends).
     """
     # f32/bf16 stream no scales: the astype upcast is the whole dequant
     scale = buf_scale if precision == "int8" else None
     with jax.named_scope("scan"):
+        n_tiles = None     # the kernels' streamed tiles per grid row
         if backend == "pallas":
             from repro.kernels import fused_topk_score as fts
             score, ids = fts.fused_topk_score_routed(
                 q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids, w_hat,
                 k=k, dist_max=dist_max, block_n=block_n, buf_scale=scale,
-                buf_attrs=buf_attrs, q_filt=q_filt, interpret=interpret)
+                buf_attrs=buf_attrs, q_filt=q_filt, n_valid=n_valid,
+                interpret=interpret)
+            n_tiles, per_row = fts.routed_tiles(
+                buf_ids, top_c, block_n=block_n, n_valid=n_valid)
         elif backend == "pallas-cm":
             # cluster-major (DESIGN.md §10): dedupe the routed clusters,
             # stream each distinct one ONCE against its query roster
@@ -465,11 +474,16 @@ def _routed_topk(q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids,
                 top_c, n_clusters=buf_emb.shape[0], qcap=qcap)
             qidx = serving_lib.roster_query_rows(roster, cr=cr, n_total=n)
             q_filt_r = q_filt[qidx] if q_filt is not None else None
+            # pairs of padding rows follow the queries' (roster values)
+            n_live = n if n_valid is None else n_valid * cr
             ps, pi = fts.fused_topk_score_cluster_major(
                 q_emb[qidx], q_loc[qidx], w[qidx], u, roster,
                 buf_emb, buf_loc, buf_ids, w_hat, k=k, dist_max=dist_max,
                 n_total=n, block_n=block_n, buf_scale=scale,
-                buf_attrs=buf_attrs, q_filt_r=q_filt_r, interpret=interpret)
+                buf_attrs=buf_attrs, q_filt_r=q_filt_r, n_live=n_live,
+                interpret=interpret)
+            n_tiles, per_row = fts.cluster_major_tiles(
+                buf_ids, u, roster, n_live=n_live, block_n=block_n)
             score, ids = merge_cluster_major(ps, pi, roster, b=b, cr=cr, k=k)
         elif backend == "dense-cm":
             score, ids = dense_cluster_major(
@@ -481,7 +495,9 @@ def _routed_topk(q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids,
                 q_emb, q_loc, w, top_c, buf_emb, buf_loc, buf_ids, w_hat,
                 k=k, dist_max=dist_max, buf_scale=scale,
                 buf_attrs=buf_attrs, q_filt=q_filt)
-    return ids, score
+        tiles = (jnp.zeros((2,), jnp.int32) if n_tiles is None else
+                 jnp.stack([jnp.sum(n_tiles), n_tiles.size * per_row]))
+    return ids, score, tiles
 
 
 def make_query_fn(cfg, *, cr: int = 1, k: int = 20, backend: str = "auto",
@@ -497,8 +513,10 @@ def make_query_fn(cfg, *, cr: int = 1, k: int = 20, backend: str = "auto",
     resident objects, and keep the top ``k``.
 
     signature: fn(rel_params, index_params, w_hat, norm, buf_emb,
-                  buf_loc, buf_ids, buf_scale, q_tokens, q_mask, q_loc)
-               -> (ids (B, k) global object ids, scores (B, k))
+                  buf_loc, buf_ids, buf_scale, q_tokens, q_mask, q_loc,
+                  n_valid=None)
+               -> (ids (B, k) global object ids, scores (B, k),
+                   tiles (2,) int32)
 
     where ``rel_params`` / ``index_params`` are the trained relevance
     and cluster-classifier params, ``w_hat (t,)`` is the serve-form
@@ -508,7 +526,12 @@ def make_query_fn(cfg, *, cr: int = 1, k: int = 20, backend: str = "auto",
     ``buf_scale (c, cap)`` the per-row dequant scales of quantized
     buffers (``index.quantize_rows``; all-ones, and unused, below
     int8). Rows past the valid candidates come back as
-    ``(-1, NEG_INF)`` pairs.
+    ``(-1, NEG_INF)`` pairs. ``n_valid`` (int32 scalar; default all)
+    counts the batch's leading rows that are queries, as
+    :func:`run_batched` passes it: the kernels scan nothing for the
+    zero-padding rows after them. ``tiles`` counts the scan kernel's
+    grid tiles that were streamed (the live tiles of the clusters the
+    queries routed to) and all its tiles (zeros on the dense backends).
 
     Keyword args: ``cr`` routed clusters per query; ``k`` results per
     query; ``backend``/``interpret`` per the module docstring
@@ -543,7 +566,8 @@ def make_query_fn(cfg, *, cr: int = 1, k: int = 20, backend: str = "auto",
                          f"got {precision!r}")
 
     def _run(rel_params, index_params, w_hat, norm, buf_emb, buf_loc,
-             buf_ids, buf_scale, q_tokens, q_mask, q_loc, buf_attrs, q_filt):
+             buf_ids, buf_scale, q_tokens, q_mask, q_loc, buf_attrs, q_filt,
+             n_valid):
         q_emb, top_c, w = _encode_and_route(
             cfg, rel_params, index_params, norm, q_tokens, q_mask, q_loc,
             cr=cr, weight_mode=weight_mode)
@@ -551,21 +575,23 @@ def make_query_fn(cfg, *, cr: int = 1, k: int = 20, backend: str = "auto",
                             buf_ids, buf_scale, w_hat, k=k, backend=backend,
                             interpret=interpret, dist_max=dist_max,
                             block_n=block_n, precision=precision,
-                            buf_attrs=buf_attrs, q_filt=q_filt)
+                            buf_attrs=buf_attrs, q_filt=q_filt,
+                            n_valid=n_valid)
 
     if filtered:
         def query_fn(rel_params, index_params, w_hat, norm, buf_emb,
                      buf_loc, buf_ids, buf_scale, buf_attrs, q_tokens,
-                     q_mask, q_loc, q_filt):
+                     q_mask, q_loc, q_filt, n_valid=None):
             return _run(rel_params, index_params, w_hat, norm, buf_emb,
                         buf_loc, buf_ids, buf_scale, q_tokens, q_mask,
-                        q_loc, buf_attrs, q_filt)
+                        q_loc, buf_attrs, q_filt, n_valid)
     else:
         def query_fn(rel_params, index_params, w_hat, norm, buf_emb,
-                     buf_loc, buf_ids, buf_scale, q_tokens, q_mask, q_loc):
+                     buf_loc, buf_ids, buf_scale, q_tokens, q_mask, q_loc,
+                     n_valid=None):
             return _run(rel_params, index_params, w_hat, norm, buf_emb,
                         buf_loc, buf_ids, buf_scale, q_tokens, q_mask,
-                        q_loc, None, None)
+                        q_loc, None, None, n_valid)
 
     return jax.jit(query_fn)
 
@@ -654,7 +680,7 @@ def make_shard_topk_fn(*, k: int = 20, backend: str = "dense",
                                 backend=backend, interpret=interpret,
                                 dist_max=dist_max, block_n=block_n,
                                 precision=precision, buf_attrs=buf_attrs,
-                                q_filt=q_filt)
+                                q_filt=q_filt)[:2]
     else:
         def shard_fn(w_hat, buf_emb, buf_loc, buf_ids, buf_scale,
                      q_emb, q_loc, w, top_c):
@@ -662,7 +688,7 @@ def make_shard_topk_fn(*, k: int = 20, backend: str = "dense",
                                 buf_ids, buf_scale, w_hat, k=k,
                                 backend=backend, interpret=interpret,
                                 dist_max=dist_max, block_n=block_n,
-                                precision=precision)
+                                precision=precision)[:2]
 
     return jax.jit(shard_fn)
 
@@ -841,7 +867,8 @@ def pad_leading(arr, batch: int):
     return np.pad(arr, ((0, batch - n),) + ((0, 0),) * (arr.ndim - 1))
 
 
-def run_batched(fn: Callable, arrays: Sequence[np.ndarray], *, batch: int):
+def run_batched(fn: Callable, arrays: Sequence[np.ndarray], *, batch: int,
+                chunk_outputs: int = 0, with_rows: bool = False):
     """Map a jitted ``fn`` over ``arrays`` in static-shape chunks.
 
     ``arrays`` is a sequence of equal-leading-dim inputs (e.g. tokens,
@@ -855,7 +882,12 @@ def run_batched(fn: Callable, arrays: Sequence[np.ndarray], *, batch: int):
     ``fn(*chunks) -> array | tuple of arrays`` (leading dim ``batch``);
     returns the per-chunk outputs concatenated back to leading dim
     ``n`` as ``np.ndarray`` — a single array if ``fn`` returned one,
-    else a tuple.
+    else a tuple. The last ``chunk_outputs`` outputs describe a whole
+    chunk rather than its rows (a counter, say): they are copied back
+    with the rows, never trimmed, and come back stacked, one per chunk.
+    ``with_rows=True`` passes ``fn`` one more argument, last: the
+    chunk's real row count (an int32 scalar, traced under jit), so it
+    can skip the padding rows' work.
 
     Padding rows are all-zeros; make sure ``fn`` is row-independent
     (every query-phase function here is), so pad rows can't perturb
@@ -886,23 +918,30 @@ def run_batched(fn: Callable, arrays: Sequence[np.ndarray], *, batch: int):
 
     def sync(p_res, p_rows):
         with annotate("repro.sync"):
-            for o, r in zip(outs, p_res):
-                o.append(np.asarray(r)[:p_rows])
+            # one copy back of all outputs: device_get starts every
+            # transfer before it waits on the first
+            for j, (o, r) in enumerate(zip(outs, jax.device_get(p_res))):
+                r = np.asarray(r)
+                o.append(r[:p_rows] if j < n_rows else r)
 
     for s in range(0, n, batch):
         e = min(s + batch, n)
         with annotate("repro.dispatch"):
             chunk = [pad_leading(np.asarray(a[s:e]), batch) for a in arrays]
+            if with_rows:
+                chunk.append(np.int32(e - s))
             res = fn(*[jnp.asarray(c) for c in chunk])  # dispatch, no sync
         res = res if isinstance(res, (tuple, list)) else (res,)
         if outs is None:
             outs = [[] for _ in res]
+            n_rows = len(res) - chunk_outputs
         if pending is not None:
             sync(*pending)                              # chunk i-1
         pending = (res, e - s)
     if pending is not None:
         sync(*pending)
-    cat = tuple(np.concatenate(o, axis=0) for o in outs)
+    cat = tuple(np.concatenate(o, axis=0) if j < n_rows else np.stack(o)
+                for j, o in enumerate(outs))
     return cat if len(cat) > 1 else cat[0]
 
 
@@ -945,8 +984,11 @@ class QueryEngine:
         self.last_dedup_factor: Optional[float] = None
         # encoder_passes: chunks dispatched of plans that run the query
         # tower (query plans, the auto pick's route, the sharded prefix,
-        # delta scans) — a flush that encodes its rows once reads 1
-        self.stats = {"encoder_passes": 0}
+        # delta scans) — a flush that encodes its rows once reads 1;
+        # scan_tiles_live / scan_tiles_grid: the scan kernels' grid tiles
+        # streamed (live rows of the queries' clusters), and all of them
+        self.stats = {"encoder_passes": 0, "scan_tiles_live": 0,
+                      "scan_tiles_grid": 0}
         self.max_plans = int(max_plans)
         self._plans: "collections.OrderedDict" = collections.OrderedDict()
         self._route_plans = {}          # keyed cr: tiny, never evicted
@@ -1102,11 +1144,11 @@ class QueryEngine:
             snap.rel_params, snap.index_params, snap.norm,
             jnp.asarray(q_tokens), jnp.asarray(q_mask), jnp.asarray(q_loc))
 
-    def _run_encoding(self, fn, arrays, *, batch: int):
+    def _run_encoding(self, fn, arrays, *, batch: int, **kw):
         """:func:`run_batched` over a plan that runs the query tower,
         counting one encoder pass per chunk (``stats``)."""
         self.stats["encoder_passes"] += -(-np.shape(arrays[0])[0] // batch)
-        return run_batched(fn, arrays, batch=batch)
+        return run_batched(fn, arrays, batch=batch, **kw)
 
     @functools.partial(jax.profiler.annotate_function, name="repro.pick")
     def pick_backend(self, q_tokens, q_mask, q_loc, *, cr: int, batch: int,
@@ -1551,19 +1593,25 @@ class QueryEngine:
                                filtered=filtered)
             w_hat = snap.w_hat          # once per call, not per chunk
             if filtered:
-                ids, scores = self._run_encoding(
-                    lambda t, m, l, f: fn(
+                ids, scores, tiles = self._run_encoding(
+                    lambda t, m, l, f, nv: fn(
                         snap.rel_params, snap.index_params, w_hat,
                         snap.norm, buf["emb"], buf["loc"], buf["ids"],
-                        buf["scale"], buf["attrs"], t, m, l, f),
-                    [q_tokens, q_mask, q_loc, fvals], batch=batch)
+                        buf["scale"], buf["attrs"], t, m, l, f, nv),
+                    [q_tokens, q_mask, q_loc, fvals], batch=batch,
+                    chunk_outputs=1, with_rows=True)
             else:
-                ids, scores = self._run_encoding(
-                    lambda t, m, l: fn(snap.rel_params, snap.index_params,
-                                       w_hat, snap.norm, buf["emb"],
-                                       buf["loc"], buf["ids"],
-                                       buf["scale"], t, m, l),
-                    [q_tokens, q_mask, q_loc], batch=batch)
+                ids, scores, tiles = self._run_encoding(
+                    lambda t, m, l, nv: fn(snap.rel_params,
+                                           snap.index_params, w_hat,
+                                           snap.norm, buf["emb"],
+                                           buf["loc"], buf["ids"],
+                                           buf["scale"], t, m, l, nv),
+                    [q_tokens, q_mask, q_loc], batch=batch,
+                    chunk_outputs=1, with_rows=True)
+            live, grid = tiles.sum(axis=0)
+            self.stats["scan_tiles_live"] += int(live)
+            self.stats["scan_tiles_grid"] += int(grid)
         if not use_delta:
             return ids, scores
         with jax.profiler.TraceAnnotation("repro.delta"):

@@ -225,6 +225,8 @@ class ServerStats:
     engine_batches: int = 0
     engine_queries: int = 0            # real (unpadded) rows sent on-device
     encoder_passes: int = 0            # query-tower chunks those batches ran
+    scan_tiles_live: int = 0           # scan kernel tiles streamed
+    scan_tiles_grid: int = 0           # of the kernels' grid tiles
     flushes: Dict[str, int] = dataclasses.field(
         default_factory=lambda: {"size": 0, "deadline": 0, "drain": 0})
     invalidations: int = 0
@@ -1049,7 +1051,8 @@ class StreamingServer:
         if self._breaker_open and fallback is not None:
             backend = fallback
         t0 = time.perf_counter()
-        passes0 = self.engine.stats["encoder_passes"]
+        counted = ("encoder_passes", "scan_tiles_live", "scan_tiles_grid")
+        before = [self.engine.stats[c] for c in counted]
         try:
             faults_lib.fire("flush.slow")        # callback sleeps
             faults_lib.fire("flush.engine")      # armed → raises in-place
@@ -1068,8 +1071,9 @@ class StreamingServer:
                 self.stats.breaker_trips += 1
             raise
         dt = time.perf_counter() - t0
-        self.stats.encoder_passes += (self.engine.stats["encoder_passes"]
-                                      - passes0)
+        for c, b in zip(counted, before):
+            setattr(self.stats, c,
+                    getattr(self.stats, c) + self.engine.stats[c] - b)
         self._flush_monitor.record("flush", dt)
         if self._flush_monitor.slow("flush"):
             self.stats.slow_flushes += 1
@@ -1142,6 +1146,10 @@ class StreamingServer:
             "batch_fill": s.engine_queries / filled if filled else 0.0,
             "encoder_passes_per_flush": (s.encoder_passes / s.engine_batches
                                          if s.engine_batches else 0.0),
+            # share of the scan kernels' grid tiles streamed: live rows
+            # of the queries' clusters (None until a kernel has scanned)
+            "scan_live_tile_share": (s.scan_tiles_live / s.scan_tiles_grid
+                                     if s.scan_tiles_grid else None),
             "latency_ms": latency_percentiles(s.latencies_s),
             # mean waits of a flushed request (ServerStats.wait_s)
             **waits_ms,

@@ -53,6 +53,19 @@ Filtered search (DESIGN.md §13): with a ``(c, cap, 3)`` int32 attribute
 buffer and per-query compiled filter rows, each tile's attribute strip
 streams beside the embeddings and failing rows take the padding
 semantics in VMEM. The unfiltered call streams no attribute bytes.
+
+Live extent (DESIGN.md §3): rows past a cluster's last live slot are
+never read. A cluster's extent is one past its last slot with an id
+``>= 0`` (:func:`live_extent`; holes left by deletes lie inside it, so
+``counts`` may be smaller), and both kernels scalar-prefetch, beside the
+cluster ids, the live tile count ``ceil(extent / block_n)`` of every
+grid row (:func:`routed_tiles`, :func:`cluster_major_tiles`). Past it
+the index maps repeat the last live tile, so no DMA is issued, and the
+body does not run: those rows are padding (id -1, ``NEG_INF``) and, as
+ties go to the running list, could never enter the top-k. A grid row
+that serves no query (a batch's zero-padding rows, a plan row whose
+roster holds only those or nothing) streams no tile and returns the
+padding pair.
 """
 from __future__ import annotations
 
@@ -98,6 +111,44 @@ def _scan_tile(cap: int, block_n: int, *, who: str) -> int:
             f"(build_cluster_buffers rounds to multiples of 128)",
             stacklevel=3)
     return tile
+
+
+def live_extent(buf_ids):
+    """``(c,)`` int32: one past each cluster's last slot with an id
+    ``>= 0``, 0 for an empty cluster."""
+    slot = jnp.arange(1, buf_ids.shape[1] + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(buf_ids >= 0, slot, 0), axis=1)
+
+
+def _grid_tiles(buf_ids, clusters, live, *, block_n: int):
+    """``ceil(extent / tile)`` of the cluster each entry of ``clusters``
+    names, 0 where ``live`` is False. → (int32 array shaped like
+    ``clusters``, the grid's tiles per row ``cap // tile``)."""
+    cap = buf_ids.shape[1]
+    bn = _scan_tile(cap, block_n, who="scan tiles")
+    n = -(-live_extent(buf_ids)[clusters] // bn)
+    return jnp.where(live, n, 0).astype(jnp.int32), cap // bn
+
+
+def routed_tiles(buf_ids, top_c, *, block_n: int, n_valid=None):
+    """The tiles :func:`fused_topk_score_routed` streams for each routed
+    pair ``(b, r)``: all the live ones of cluster ``top_c[b, r]``, none
+    for a batch row ``b >= n_valid`` (padding; default: every row is a
+    query). → ((B, cr) int32, the grid's tiles per pair)."""
+    b = top_c.shape[0]
+    live = (jnp.ones((b, 1), bool) if n_valid is None else
+            (jnp.arange(b) < n_valid)[:, None])
+    return _grid_tiles(buf_ids, top_c, live, block_n=block_n)
+
+
+def cluster_major_tiles(buf_ids, u, roster, *, n_live, block_n: int):
+    """The tiles :func:`fused_topk_score_cluster_major` streams for each
+    plan row ``i``: all the live ones of cluster ``u[i]``, none for a row
+    whose roster holds no entry below ``n_live`` (empty slots hold
+    ``n_total``; entries in ``[n_live, n_total)`` are the batch's
+    padding pairs). → ((rows,) int32, the grid's tiles per row)."""
+    return _grid_tiles(buf_ids, u, jnp.any(roster < n_live, axis=1),
+                       block_n=block_n)
 
 
 def _step_table(w_hat):
@@ -182,18 +233,23 @@ def _merge_topk(run_s, run_i, st, ids, k: int):
     return out_s, out_i
 
 
-def _scan_kernel(*refs, n_prefetch: int, first_step, dequant: bool,
+def _scan_kernel(*refs, row_of, n_axes: int, dequant: bool,
                  filtered: bool, k: int, t: int, dist_max: float):
     """Score one ``(block_n, d)`` resident tile against ``m`` query rows
     (1 for the routed kernel, the roster's ``Qcap`` for cluster-major)
     and fold it into each row's running top-k.
 
-    Refs after the scalar-prefetch ones: q ``(1, m, d)``, q_loc
-    ``(1, m, 2)``, w_st ``(1, m, 2)``, step table ``(t/128, 128)``, emb
-    ``(1, bn, d)``, [scale ``(1, 1, bn)``], loc ``(1, 2, bn)``, ids
-    ``(1, 1, bn)``, [attrs ``(1, 3, bn)``, q_filt ``(1, m, 4)``], then
-    the outputs scores / ids ``(1, m, k)``."""
-    refs = list(refs[n_prefetch:])
+    The grid's last axis walks the tiles; the axes before it name a grid
+    row, ``row_of(*those)`` its entry in the scalar-prefetched cluster
+    ids and live tile counts, the first two refs. The output block is
+    the first axis's, initialised where every later axis is 0. Refs
+    after those two: q ``(1, m, d)``, q_loc ``(1, m, 2)``, w_st
+    ``(1, m, 2)``, step table ``(t/128, 128)``, emb ``(1, bn, d)``,
+    [scale ``(1, 1, bn)``], loc ``(1, 2, bn)``, ids ``(1, 1, bn)``,
+    [attrs ``(1, 3, bn)``, q_filt ``(1, m, 4)``], then the outputs
+    scores / ids ``(1, m, k)``."""
+    tiles_ref = refs[1]
+    refs = list(refs[2:])
     qe_ref, ql_ref, qw_ref, tab_ref, emb_ref = refs[:5]
     del refs[:5]
     scale_ref = refs.pop(0) if dequant else None
@@ -202,56 +258,73 @@ def _scan_kernel(*refs, n_prefetch: int, first_step, dequant: bool,
     if filtered:
         attrs_ref, qf_ref = refs.pop(0), refs.pop(0)
     os_ref, oi_ref = refs
+    axes = [pl.program_id(a) for a in range(n_axes)]
 
-    @pl.when(first_step())
+    @pl.when(functools.reduce(jnp.logical_and,
+                              [a == 0 for a in axes[1:]]))
     def _init():
         os_ref[...] = jnp.full(os_ref.shape, NEG_INF, jnp.float32)
         oi_ref[...] = jnp.full(oi_ref.shape, -1, jnp.int32)
 
-    q = qe_ref[0].astype(jnp.float32)                         # (m, d)
-    trel = jax.lax.dot_general(
-        q, emb_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)                  # (m, bn)
-    if dequant:
-        trel = trel * scale_ref[0]                            # per-row scale
+    @pl.when(axes[-1] < tiles_ref[row_of(*axes[:-1])])
+    def _scan():
+        q = qe_ref[0].astype(jnp.float32)                     # (m, d)
+        trel = jax.lax.dot_general(
+            q, emb_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)              # (m, bn)
+        if dequant:
+            trel = trel * scale_ref[0]                        # per-row scale
 
-    ql = ql_ref[0].astype(jnp.float32)                        # (m, 2)
-    ol = loc_ref[0]                                           # (2, bn)
-    dx = ql[:, 0:1] - ol[0:1, :]
-    dy = ql[:, 1:2] - ol[1:2, :]
-    dist = jnp.sqrt(dx * dx + dy * dy)                        # (m, bn)
-    s_in = 1.0 - jnp.clip(dist / dist_max, 0.0, 1.0)
-    idx = jnp.clip((s_in * t).astype(jnp.int32), 0, t - 1)
-    srel = _step_lookup(tab_ref[...], idx)
+        ql = ql_ref[0].astype(jnp.float32)                    # (m, 2)
+        ol = loc_ref[0]                                       # (2, bn)
+        dx = ql[:, 0:1] - ol[0:1, :]
+        dy = ql[:, 1:2] - ol[1:2, :]
+        dist = jnp.sqrt(dx * dx + dy * dy)                    # (m, bn)
+        s_in = 1.0 - jnp.clip(dist / dist_max, 0.0, 1.0)
+        idx = jnp.clip((s_in * t).astype(jnp.int32), 0, t - 1)
+        srel = _step_lookup(tab_ref[...], idx)
 
-    w = qw_ref[0].astype(jnp.float32)                         # (m, 2)
-    st = w[:, 0:1] * trel + w[:, 1:2] * srel
-    ids = ids_ref[0]                                          # (1, bn)
-    valid = ids >= 0                                          # buffer pad
-    if filtered:
-        valid = valid & _predicate(attrs_ref[0], qf_ref[0])   # (m, bn)
-    st = jnp.where(valid, st, NEG_INF)
-    ids = jnp.where(valid, ids, -1)
-    ids = jnp.broadcast_to(ids, st.shape)
+        w = qw_ref[0].astype(jnp.float32)                     # (m, 2)
+        st = w[:, 0:1] * trel + w[:, 1:2] * srel
+        ids = ids_ref[0]                                      # (1, bn)
+        valid = ids >= 0                                      # buffer pad
+        if filtered:
+            valid = valid & _predicate(attrs_ref[0], qf_ref[0])   # (m, bn)
+        st = jnp.where(valid, st, NEG_INF)
+        ids = jnp.where(valid, ids, -1)
+        ids = jnp.broadcast_to(ids, st.shape)
 
-    s, i = _merge_topk(os_ref[0], oi_ref[0], st, ids, k)
-    os_ref[0] = s
-    oi_ref[0] = i
+        s, i = _merge_topk(os_ref[0], oi_ref[0], st, ids, k)
+        os_ref[0] = s
+        oi_ref[0] = i
 
 
 def _buffer_operands(buf_emb, buf_loc, buf_ids, buf_scale, buf_attrs, *,
-                     block_n: int, cluster_of, who: str):
-    """BlockSpecs + operands for the resident buffers, blocked by tile
-    ``j`` of the cluster ``cluster_of(*grid_idx_and_prefetch)`` picks."""
+                     block_n: int, row_of, who: str):
+    """BlockSpecs + operands for the resident buffers. Grid step
+    ``(*row, j)`` reads tile ``j`` of the cluster that prefetched entry
+    ``row_of(*row)`` names, clamped to that entry's last live tile: past
+    it the block index repeats, so nothing more is copied in."""
     c, cap, d = buf_emb.shape
     bn = _scan_tile(cap, block_n, who=who)
 
-    def lane(rows):
-        return pl.BlockSpec((1, rows, bn),
-                            lambda *a: (cluster_of(*a), 0, a[-2]))
+    def cluster_tile(*a):
+        *row, j, clusters, tiles = a
+        r = row_of(*row)
+        return clusters[r], jnp.minimum(j, jnp.maximum(tiles[r] - 1, 0))
 
-    specs = [pl.BlockSpec((1, bn, d), lambda *a: (cluster_of(*a), a[-2], 0))]
+    def lane(rows):
+        def index(*a):
+            cl, j = cluster_tile(*a)
+            return cl, 0, j
+        return pl.BlockSpec((1, rows, bn), index)
+
+    def rows_first(*a):
+        cl, j = cluster_tile(*a)
+        return cl, j, 0
+
+    specs = [pl.BlockSpec((1, bn, d), rows_first)]
     args = [buf_emb]
     # the lane-major copies of the side buffers, made on every call:
     # named so a profile shows what they cost
@@ -271,7 +344,8 @@ def _buffer_operands(buf_emb, buf_loc, buf_ids, buf_scale, buf_attrs, *,
 def fused_topk_score_routed(q_emb, q_loc, w_st, top_c, buf_emb, buf_loc,
                             buf_ids, w_hat, *, k: int, dist_max: float,
                             interpret: bool, block_n: int = 512,
-                            buf_scale=None, buf_attrs=None, q_filt=None):
+                            buf_scale=None, buf_attrs=None, q_filt=None,
+                            n_valid=None):
     """Gather-free fused score + top-k over routed cluster buffers.
 
     q_emb (B, d); q_loc (B, 2); w_st (B, 2); top_c (B, cr) int32 routed
@@ -289,22 +363,30 @@ def fused_topk_score_routed(q_emb, q_loc, w_st, top_c, buf_emb, buf_loc,
     -1 where fewer than k valid candidates exist). Grid ``(B, cr,
     cap/block_n)``: step ``(b, r, j)`` streams tile ``j`` of resident
     cluster ``top_c[b, r]``, and the cr routed lists fold into one
-    running top-k in VMEM. ``interpret`` runs the Pallas interpreter
-    (off the chip) instead of compiling with Mosaic.
+    running top-k in VMEM; only the cluster's live tiles are read and
+    scored (:func:`routed_tiles`). ``n_valid`` (an int32 scalar, may be
+    traced) marks the rows from ``n_valid`` on as the batch's padding:
+    they stream nothing and return padding pairs. ``interpret`` runs the Pallas interpreter (off the chip) instead of
+    compiling with Mosaic.
     """
     b, d = q_emb.shape
     cr = top_c.shape[1]
     if (buf_attrs is None) != (q_filt is None):
         raise ValueError("fused_topk_score_routed: pass buf_attrs and "
                          "q_filt together or not at all")
+    n_tiles, _ = routed_tiles(buf_ids, top_c, block_n=block_n,
+                              n_valid=n_valid)
+
+    def row_of(b_, r):
+        return b_ * cr + r
+
     buf_specs, buf_args, bn = _buffer_operands(
         buf_emb, buf_loc, buf_ids, buf_scale, buf_attrs, block_n=block_n,
-        cluster_of=lambda b_, r, j, tc: tc[b_ * cr + r],
-        who="fused_topk_score_routed")
+        row_of=row_of, who="fused_topk_score_routed")
     tab = _step_table(w_hat)
 
     def per_query(width):
-        return pl.BlockSpec((1, 1, width), lambda b_, r, j, tc: (b_, 0, 0))
+        return pl.BlockSpec((1, 1, width), lambda b_, r, j, *_: (b_, 0, 0))
 
     in_specs = [per_query(d), per_query(2), per_query(2),
                 pl.BlockSpec(tab.shape, lambda *_: (0, 0)), *buf_specs]
@@ -314,19 +396,18 @@ def fused_topk_score_routed(q_emb, q_loc, w_st, top_c, buf_emb, buf_loc,
         in_specs.append(per_query(4))
         args.append(q_filt.astype(jnp.int32).reshape(b, 1, 4))
     kern = functools.partial(
-        _scan_kernel, n_prefetch=1,
-        first_step=lambda: (pl.program_id(1) == 0) & (pl.program_id(2) == 0),
+        _scan_kernel, row_of=row_of, n_axes=3,
         dequant=buf_scale is not None, filtered=q_filt is not None, k=k,
         t=w_hat.shape[0], dist_max=float(dist_max))
     scores, ids = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, cr, buf_emb.shape[1] // bn),
+            num_scalar_prefetch=2, grid=(b, cr, buf_emb.shape[1] // bn),
             in_specs=in_specs, out_specs=[per_query(k), per_query(k)]),
         out_shape=[jax.ShapeDtypeStruct((b, 1, k), jnp.float32),
                    jax.ShapeDtypeStruct((b, 1, k), jnp.int32)],
         interpret=interpret,
-    )(top_c.reshape(-1).astype(jnp.int32), *args)
+    )(top_c.reshape(-1).astype(jnp.int32), n_tiles.reshape(-1), *args)
     return scores.reshape(b, k), ids.reshape(b, k)
 
 
@@ -335,7 +416,7 @@ def fused_topk_score_cluster_major(q_emb_r, q_loc_r, w_st_r, u, roster,
                                    k: int, dist_max: float, n_total: int,
                                    interpret: bool, block_n: int = 512,
                                    buf_scale=None, buf_attrs=None,
-                                   q_filt_r=None):
+                                   q_filt_r=None, n_live=None):
     """Cluster-major fused score + top-k over the batch plan.
 
     Inputs are the plan of ``serving.cluster_major_plan`` plus the
@@ -362,20 +443,25 @@ def fused_topk_score_cluster_major(q_emb_r, q_loc_r, w_st_r, u, roster,
     cluster ``u[i]`` and scores it against the row's whole roster in one
     ``(Qcap, d) × (d, block_n)`` MXU matmul — each distinct cluster's
     bytes cross HBM once per plan row instead of once per routed query.
-    ``Qcap`` bounds the query block held in VMEM.
+    Only the cluster's live tiles are read and scored, none on a row
+    that serves no query (:func:`cluster_major_tiles`): ``n_live`` (an
+    int32 scalar, may be traced; default ``n_total``) marks the roster
+    entries from it on as the batch's padding pairs. ``Qcap`` bounds the query block held in VMEM.
     """
     rows, qcap, d = q_emb_r.shape
     if (buf_attrs is None) != (q_filt_r is None):
         raise ValueError("fused_topk_score_cluster_major: pass buf_attrs "
                          "and q_filt_r together or not at all")
+    n_tiles, _ = cluster_major_tiles(
+        buf_ids, u, roster, block_n=block_n,
+        n_live=n_total if n_live is None else n_live)
     buf_specs, buf_args, bn = _buffer_operands(
         buf_emb, buf_loc, buf_ids, buf_scale, buf_attrs, block_n=block_n,
-        cluster_of=lambda i, j, u_: u_[i],
-        who="fused_topk_score_cluster_major")
+        row_of=lambda i: i, who="fused_topk_score_cluster_major")
     tab = _step_table(w_hat)
 
     def per_row(width):
-        return pl.BlockSpec((1, qcap, width), lambda i, j, u_: (i, 0, 0))
+        return pl.BlockSpec((1, qcap, width), lambda i, j, *_: (i, 0, 0))
 
     in_specs = [per_row(d), per_row(2), per_row(2),
                 pl.BlockSpec(tab.shape, lambda *_: (0, 0)), *buf_specs]
@@ -384,19 +470,18 @@ def fused_topk_score_cluster_major(q_emb_r, q_loc_r, w_st_r, u, roster,
         in_specs.append(per_row(4))
         args.append(q_filt_r.astype(jnp.int32))
     kern = functools.partial(
-        _scan_kernel, n_prefetch=1,
-        first_step=lambda: pl.program_id(1) == 0,
+        _scan_kernel, row_of=lambda i: i, n_axes=2,
         dequant=buf_scale is not None, filtered=q_filt_r is not None, k=k,
         t=w_hat.shape[0], dist_max=float(dist_max))
     scores, ids = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(rows, buf_emb.shape[1] // bn),
+            num_scalar_prefetch=2, grid=(rows, buf_emb.shape[1] // bn),
             in_specs=in_specs, out_specs=[per_row(k), per_row(k)]),
         out_shape=[jax.ShapeDtypeStruct((rows, qcap, k), jnp.float32),
                    jax.ShapeDtypeStruct((rows, qcap, k), jnp.int32)],
         interpret=interpret,
-    )(u.astype(jnp.int32), *args)
+    )(u.astype(jnp.int32), n_tiles, *args)
     # empty roster slots scored whatever query row 0 is: null them to
     # the padding pair so every slot honours the contract
     live = (roster < n_total)[..., None]

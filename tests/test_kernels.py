@@ -155,6 +155,124 @@ def test_fused_topk_masks_padding(rng):
     assert (np.asarray(i) < k).all() and (np.asarray(i) >= 0).all()
 
 
+def _extent_buffers(rng, *, cap, d, precision, attrs):
+    """Seven clusters of capacity ``cap`` whose live extents span every
+    edge of a 128-row tile grid: full, empty, one row, mid-tile, on a
+    tile boundary, interior holes (``delete_objects``), and live rows
+    after a hole near the end (``counts`` 12, extent ``cap``)."""
+    from repro.core import index as il
+    fill = [cap, 0, 1, 200, 256, 400, cap]
+    c = len(fill)
+    ids = np.full((c, cap), -1, np.int32)
+    for ci, n in enumerate(fill):
+        ids[ci, :n] = ci * cap + np.arange(n)
+    emb = rng.normal(size=(c, cap, d)).astype(np.float32)
+    emb[ids < 0] = 0.0
+    be, bs = il.quantize_rows(emb, precision)
+    loc = rng.uniform(size=(c, cap, 2)).astype(np.float32)
+    loc[ids < 0] = il.PAD_LOC
+    att = np.stack([rng.integers(0, 2, (c, cap)), rng.integers(1, 4, (c, cap)),
+                    rng.integers(0, 100, (c, cap))], -1).astype(np.int32)
+    att[ids < 0] = 0
+    buf = {"emb": jnp.asarray(be), "scale": jnp.asarray(bs),
+           "loc": jnp.asarray(loc), "ids": jnp.asarray(ids),
+           "attrs": jnp.asarray(att), "counts": jnp.asarray(fill)}
+    holes = np.concatenate([5 * cap + np.arange(100, 300, 3),
+                            6 * cap + np.arange(10, cap - 2)])
+    buf = il.delete_objects(buf, holes)
+    assert np.asarray(buf["counts"])[6] == 12
+    return buf, (buf["attrs"] if attrs else None)
+
+
+@pytest.mark.parametrize("kernel,precision,filtered,n_valid", [
+    pytest.param("routed", "f32", False, None, id="routed-f32-False"),
+    pytest.param("routed", "int8", False, None, id="routed-int8-False"),
+    pytest.param("cluster_major", "f32", False, None,
+                 id="cluster_major-f32-False"),
+    pytest.param("cluster_major", "int8", False, None,
+                 id="cluster_major-int8-False"),
+    pytest.param("cluster_major", "f32", True, None,
+                 id="cluster_major-f32-True"),
+    pytest.param("routed", "f32", False, 5, id="routed-f32-padded"),
+    pytest.param("routed", "int8", True, 3, id="routed-int8-filtered-padded"),
+    pytest.param("cluster_major", "f32", False, 3,
+                 id="cluster_major-f32-padded"),
+    pytest.param("cluster_major", "int8", False, 5,
+                 id="cluster_major-int8-padded"),
+])
+def test_scan_reads_only_live_extent(kernel, precision, filtered, n_valid,
+                                     rng):
+    """The kernels stream each cluster only up to its last live slot:
+    every extent edge — empty, one row, mid-tile, on a tile boundary,
+    full, interior holes, live rows after a hole near the end — and a
+    cluster-major plan with empty rows equal the dense oracle. A scan
+    bounded by ``counts`` would miss cluster 6's last two rows. With
+    ``n_valid``, the batch rows from it on are padding: they stream no
+    tile (routed: their answers are padding pairs) while the queries'
+    answers stay the oracle's."""
+    from repro.core import serving
+    from repro.core.engine import dense_routed_topk, merge_cluster_major
+    from repro.kernels import fused_topk_score as fts
+    cap, d, k, bn = 512, 16, 20, 128
+    buf, attrs = _extent_buffers(rng, cap=cap, d=d, precision=precision,
+                                 attrs=filtered)
+    top_c = jnp.asarray([[6, 1], [0, 2], [3, 4], [5, 6], [2, 3], [4, 0],
+                         [1, 5], [6, 6]], jnp.int32)
+    b, cr = top_c.shape
+    q = jnp.asarray(rng.normal(size=(b, d)), jnp.float32)
+    ql = jnp.asarray(rng.uniform(size=(b, 2)), jnp.float32)
+    w = jnp.asarray(rng.uniform(0.5, 1.5, size=(b, 2)), jnp.float32)
+    wh = jnp.asarray(np.cumsum(rng.uniform(0, 0.01, size=50)), jnp.float32)
+    q_filt = (jnp.asarray([[1, 0, -2 ** 31, 2 ** 31 - 1],
+                           [-1, 2, 10, 90]] * (b // 2), jnp.int32)
+              if filtered else None)
+    scale = buf["scale"] if precision == "int8" else None
+    common = dict(k=k, dist_max=1.414, block_n=bn, buf_scale=scale,
+                  buf_attrs=attrs, interpret=True)
+    extent_tiles = -(-np.array([cap, 0, 1, 200, 256, 400, cap]) // bn)
+    nv = b if n_valid is None else n_valid
+    if kernel == "routed":
+        s1, i1 = fts.fused_topk_score_routed(
+            q, ql, w, top_c, buf["emb"], buf["loc"], buf["ids"], wh,
+            q_filt=q_filt, n_valid=n_valid, **common)
+        tiles, per_row = fts.routed_tiles(buf["ids"], top_c, block_n=bn,
+                                          n_valid=n_valid)
+        want = np.where(np.arange(b)[:, None] < nv,
+                        extent_tiles[np.asarray(top_c)], 0)
+        assert (np.asarray(s1)[nv:] == fts.NEG_INF).all()
+        assert (np.asarray(i1)[nv:] == -1).all()
+    else:
+        n = b * cr
+        u, roster, _, _ = serving.cluster_major_plan(top_c, n_clusters=7,
+                                                     qcap=4)
+        assert (np.asarray(roster) == n).all(axis=1).sum() == 3
+        qi = serving.roster_query_rows(roster, cr=cr, n_total=n)
+        n_live = None if n_valid is None else n_valid * cr
+        ps, pi = fts.fused_topk_score_cluster_major(
+            q[qi], ql[qi], w[qi], u, roster, buf["emb"], buf["loc"],
+            buf["ids"], wh, n_total=n, n_live=n_live,
+            q_filt_r=None if q_filt is None else q_filt[qi], **common)
+        s1, i1 = merge_cluster_major(ps, pi, roster, b=b, cr=cr, k=k)
+        tiles, per_row = fts.cluster_major_tiles(
+            buf["ids"], u, roster, n_live=nv * cr, block_n=bn)
+        serves = (np.asarray(roster) < nv * cr).any(axis=1)
+        want = np.where(serves, extent_tiles[np.asarray(u)], 0)
+        if n_valid == 3:               # cluster 5 serves padding rows only
+            assert not serves[np.asarray(u) == 5].any()
+    assert per_row == cap // bn
+    assert (np.asarray(tiles) == want).all()
+    s2, i2 = dense_routed_topk(q, ql, w, top_c, buf["emb"], buf["loc"],
+                               buf["ids"], wh, k=k, dist_max=1.414,
+                               buf_scale=scale, buf_attrs=attrs,
+                               q_filt=q_filt)
+    np.testing.assert_allclose(np.asarray(s1)[:nv], np.asarray(s2)[:nv],
+                               rtol=1e-5, atol=1e-5)
+    assert (np.sort(np.asarray(i1)[:nv]) == np.sort(np.asarray(i2)[:nv])).all()
+    if not filtered:                   # query 0 sees all of cluster 6
+        assert {6 * cap + cap - 2, 6 * cap + cap - 1} <= set(
+            np.asarray(i1)[0].tolist())
+
+
 @pytest.mark.parametrize("b,s,h,kv,d,causal,window", [
     (2, 256, 4, 2, 32, True, 0),
     (1, 128, 4, 4, 64, True, 64),

@@ -177,8 +177,8 @@ def test_engine_backend_parity_end_to_end(engine_setup, cr, backend, rng):
                               dist_max=DIST_MAX)
     fp = engine.make_query_fn(cfg, cr=cr, k=k, backend=backend,
                               interpret=True, dist_max=DIST_MAX)
-    i_d, s_d = fd(*a)
-    i_p, s_p = fp(*a)
+    i_d, s_d, _ = fd(*a)
+    i_p, s_p, _ = fp(*a)
     np.testing.assert_allclose(np.asarray(s_p), np.asarray(s_d),
                                rtol=1e-4, atol=1e-4)
     assert (np.sort(np.asarray(i_p)) == np.sort(np.asarray(i_d))).all()
@@ -215,8 +215,8 @@ def test_engine_precision_tier_backend_parity(engine_setup, precision, cr,
     fp = engine.make_query_fn(cfg, cr=cr, k=k, backend=backend,
                               interpret=True, dist_max=DIST_MAX,
                               precision=precision)
-    i_d, s_d = fd(*a)
-    i_p, s_p = fp(*a)
+    i_d, s_d, _ = fd(*a)
+    i_p, s_p, _ = fp(*a)
     np.testing.assert_allclose(np.asarray(s_p), np.asarray(s_d),
                                rtol=1e-4, atol=1e-4)
     assert (np.sort(np.asarray(i_p)) == np.sort(np.asarray(i_d))).all()
@@ -238,10 +238,11 @@ def test_quantized_scores_track_f32(engine_setup, precision, rng):
                                    dist_max=DIST_MAX)
     f_quant = engine.make_query_fn(cfg, cr=cr, k=k, backend="dense",
                                    dist_max=DIST_MAX, precision=precision)
-    i_e, s_e = f_exact(params, iparams, w_hat, norm, buf["emb"], buf["loc"],
-                       buf["ids"], buf["scale"], tok, msk, ql)
-    i_q, s_q = f_quant(params, iparams, w_hat, norm, qbuf["emb"],
-                       qbuf["loc"], qbuf["ids"], qbuf["scale"], tok, msk, ql)
+    i_e, s_e, _ = f_exact(params, iparams, w_hat, norm, buf["emb"],
+                          buf["loc"], buf["ids"], buf["scale"], tok, msk, ql)
+    i_q, s_q, _ = f_quant(params, iparams, w_hat, norm, qbuf["emb"],
+                          qbuf["loc"], qbuf["ids"], qbuf["scale"], tok, msk,
+                          ql)
     # int8 per-row scalar quantization bounds the per-element embedding
     # error by scale/2; bf16 by ~2^-8 relative — both stay well under 2%
     # of the score magnitude at this scale
@@ -269,6 +270,17 @@ def test_run_batched_pads_partial_batches(rng):
     assert calls == [8, 8, 8]                  # every chunk static-shaped
     np.testing.assert_allclose(ox, x * 2, rtol=1e-6)
     np.testing.assert_allclose(oy, y + 1, rtol=1e-6)
+
+
+def test_run_batched_stacks_chunk_outputs(rng):
+    """Outputs that describe a whole chunk come back one per chunk,
+    untrimmed, beside the trimmed row outputs."""
+    x = rng.normal(size=(19, 3)).astype(np.float32)
+    out, count = engine.run_batched(
+        lambda c: (c * 2, np.array([c.shape[0], 1])), [x], batch=8,
+        chunk_outputs=1)
+    np.testing.assert_allclose(out, x * 2, rtol=1e-6)
+    assert count.tolist() == [[8, 1]] * 3
 
 
 def test_run_batched_overlaps_transfer_with_dispatch(rng):

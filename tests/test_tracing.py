@@ -30,15 +30,14 @@ from repro.core import server as server_lib
 DIST_MAX = 1.414
 
 
-@pytest.fixture(scope="module")
-def engine_parts():
+def build_parts(n, cap):
     cfg = dataclasses.replace(
         get_config("list-dual-encoder"),
         n_layers=2, d_model=32, n_heads=2, d_ff=64, vocab_size=512,
         max_len=8, spatial_t=50, n_clusters=4, index_mlp_hidden=(16,))
     rng = np.random.default_rng(3)
     params = relevance.relevance_init(jax.random.PRNGKey(0), cfg)
-    n, c, cap = 96, cfg.n_clusters, 64
+    c = cfg.n_clusters
     obj_emb = rng.normal(size=(n, cfg.d_model)).astype(np.float32)
     obj_loc = rng.uniform(size=(n, 2)).astype(np.float32)
     norm = il.loc_normalizer(jnp.asarray(obj_loc))
@@ -50,6 +49,11 @@ def engine_parts():
     buf = il.build_cluster_buffers(top, obj_emb, obj_loc, n_clusters=c,
                                    capacity=cap)
     return cfg, params, iparams, norm, buf
+
+
+@pytest.fixture(scope="module")
+def engine_parts():
+    return build_parts(96, 64)
 
 
 def make_engine(engine_parts, backend="auto"):
@@ -243,6 +247,65 @@ def test_encoder_passes_per_flush(engine_parts, backend, batch, passes):
     assert srv.stats.engine_batches == 2
     assert srv.engine.stats["encoder_passes"] == 2 * passes
     assert srv.metrics()["encoder_passes_per_flush"] == passes
+
+
+@pytest.mark.parametrize("backend", ["pallas", "pallas-cm"])
+def test_scan_tile_counters_follow_the_live_extent(backend):
+    """The live extent is the last live slot + 1 through inserts and
+    deletes (0 once a cluster is emptied, past ``counts`` once it has
+    holes), and one query call counts the kernel grid's tiles under it:
+    the queries' routed pairs (query-major) or the plan rows that serve
+    a query (cluster-major), none for the padding rows of a partial
+    chunk, against the grid's tile count."""
+    from repro.kernels import fused_topk_score as fts
+    cfg, params, iparams, norm, buf = build_parts(1500, 1024)
+    c, bn, per_row = cfg.n_clusters, 512, 2
+    rng = np.random.default_rng(7)
+    buf = il.insert_objects(
+        buf, iparams, norm,
+        jnp.asarray(rng.normal(size=(5, cfg.d_model)), jnp.float32),
+        jnp.asarray(rng.uniform(size=(5, 2)), jnp.float32),
+        np.arange(10_000, 10_005))
+    ids = np.asarray(buf["ids"])
+    fullest, emptied = np.argsort((ids >= 0).sum(1))[[-1, 0]]
+    live = ids[fullest][ids[fullest] >= 0]
+    buf = il.delete_objects(buf, np.concatenate(
+        [live[10:-3], ids[emptied][ids[emptied] >= 0]]))
+    ids = np.asarray(buf["ids"])
+    want = np.array([np.flatnonzero(r >= 0).max() + 1 if (r >= 0).any()
+                     else 0 for r in ids])
+    extent = np.asarray(fts.live_extent(buf["ids"]))
+    assert (extent == want).all()
+    assert extent[emptied] == 0
+    assert np.asarray(buf["counts"])[fullest] < extent[fullest]
+    assert extent[fullest] > bn                   # spans both tiles
+
+    eng = engine_lib.QueryEngine.from_parts(
+        cfg, params, iparams, norm, buf, dist_max=DIST_MAX, backend=backend)
+    tok, msk, loc = make_requests(5, 6, cfg)      # chunks of 4 and 2 + 2 pad
+    eng.query(tok, msk, loc, k=5, cr=2, batch=4)
+    top_c = np.asarray(eng.route(tok, msk, loc, cr=2))
+    if backend == "pallas":
+        live_tiles = int(np.sum(-(-extent[top_c] // bn)))
+        grid = 2 * top_c[:4].size * per_row
+    else:
+        live_tiles = sum(int(np.sum(-(-extent[np.unique(top_c[s:s + 4])]
+                                       // bn))) for s in (0, 4))
+        grid = 2 * min(top_c[:4].size, c) * per_row
+    assert eng.stats["scan_tiles_live"] == live_tiles
+    assert eng.stats["scan_tiles_grid"] == grid
+
+    srv = server_lib.StreamingServer(eng, server_lib.ServerConfig(
+        batch_size=4, max_delay_ms=5.0, k=5, cr=2, backend=None))
+    assert srv.metrics()["scan_live_tile_share"] is None
+    before = dict(eng.stats)
+    srv.serve_all(tok, msk, loc)
+    live, grid = (eng.stats[c] - before[c]
+                  for c in ("scan_tiles_live", "scan_tiles_grid"))
+    assert 0 < live < grid
+    assert (srv.stats.scan_tiles_live, srv.stats.scan_tiles_grid) == (live,
+                                                                      grid)
+    assert srv.metrics()["scan_live_tile_share"] == pytest.approx(live / grid)
 
 
 def test_waits_add_up_to_each_requests_latency(engine_parts):
