@@ -2,10 +2,28 @@
 result line.
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``
-names a configuration (``configs/<name>.json``, whose ``reference``
-names the plain reference in ``references/``) and a traffic mix
-(``traffic/<name>.json``); each per-layer metric is read by
-``metrics/<name>.py``.
+names a configuration (``configs/<name>.json``) and a traffic mix
+(``traffic/<name>.json``); the configuration's ``reference`` names its
+plain reference (``references/<reference>.py``) and the tower adapter
+that hands the program the same tower (``towers/<reference>.py``); each
+per-layer metric is read by ``metrics/<name>.py``. A configuration with
+a new query tower is added with those files alone.
+
+The contract between the harness and those files:
+
+* a configuration's ``model`` block has ``vocab_size`` and ``max_len``
+  (the traffic reads them) and ``n_clusters``; every other key belongs to
+  the tower, and only its reference and adapter read it;
+* a reference module exports ``init_weights(key, model)``,
+  ``calibrate_router(w, tokens, mask, loc, model)``,
+  ``encode(w, tokens, mask, model, precision)`` → (B, index d) in f32 at
+  ``HIGHEST`` (``precision`` names the control's lower step),
+  ``mixing_weights``, ``router_logits``, ``step_table``, ``score`` and
+  ``query_flops(model, lengths)``, the FLOPs the query phase needs before
+  the scan (``reduce.step_mfu``); it imports nothing of the program;
+* a tower adapter exports ``program_config(model)``, the program's
+  configuration, and ``program_params(weights)``, the reference's
+  weights in the program's layout as (relevance, index, norm) params.
 """
 from __future__ import annotations
 
@@ -164,6 +182,7 @@ class Setup:
         self.phases = {}
         t = clock()
         self.model, self.index = config["model"], config["index"]
+        system.tower_adapter(config)    # a missing adapter fails here
         self.ref = reference_module(config)
         r_index, r_weights, r_traffic, r_warm, self.r_check = seeds(seed)
         model = check_lib.Frozen(self.model)
@@ -182,7 +201,7 @@ class Setup:
                                            self.counts)
         jax.block_until_ready(self.buffers["emb"])
         self.phases["index_s"], t = clock() - t, clock()
-        self.search = system.searcher(self.model, self.weights, self.buffers)
+        self.search = system.searcher(config, self.weights, self.buffers)
         self.spans = system.StepSpans(self.search.engine)
         vocab, max_len = self.model["vocab_size"], self.model["max_len"]
         self.server = None
@@ -372,6 +391,7 @@ def run(root, workload, *, seed, seconds, trace, t_process, devices, peaks,
         lo, hi = trace_lib.window_of(tr)
         ctx = {"trace": tr, "window": (lo, hi), "steps": steps,
                "step_routes": step_routes, "counts": counts, "model": model,
+               "ref": su.ref,
                "index": index, "mix": mix, "peaks": peaks,
                "server_window": server_window}
         for m in layer:

@@ -85,8 +85,9 @@ def scan_roofline(ctx, kind):
 
 
 def step_mfu(ctx, kind):
-    """FLOPs the steps required (real tokens, router, scan of valid
-    rows) over the steps' wall time at the chip's bf16 peak."""
+    """FLOPs the steps required (the reference's ``query_flops`` on the
+    real tokens, the scan of valid rows) over the steps' wall time at the
+    chip's bf16 peak."""
     st = steps(ctx, kind)
     if st is None:
         return None
@@ -94,7 +95,7 @@ def step_mfu(ctx, kind):
     flops = 0.0
     for step, routes in zip(st, ctx["step_routes"]):
         lengths = np.asarray(step.arrays[1]).sum(-1)
-        flops += work_lib.query_flops(ctx["model"], lengths)
+        flops += ctx["ref"].query_flops(ctx["model"], lengths)
         flops += work_lib.scan_work(routes, ctx["counts"], d=idx["d"],
                                     precision=idx["precision"])[0]
     wall = sum(s.t1 - s.t0 for s in st)

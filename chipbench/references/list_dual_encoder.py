@@ -1,4 +1,5 @@
-"""Plain float32 reference of the LIST query phase (arXiv:2403.07331).
+"""Plain float32 reference of the LIST query phase (arXiv:2403.07331)
+with its BERT query tower.
 
 What the configuration describes, written out in ``jax.numpy`` with no
 kernel, cache, batching or program code:
@@ -8,12 +9,8 @@ kernel, cache, batching or program code:
   tanh-GELU feed-forward), a final LayerNorm and a tanh dense head on the
   CLS position (the paper's BERT tower, pre-LN as the configuration
   states);
-* mixing weights (Eq. 6): ``softplus(MLP(q))`` with one ReLU hidden layer;
-* router (Eq. 9-11): an MLP with ReLU hidden layers over
-  ``[q / |q|, lat, lon]`` whose logits rank the clusters;
-* score (Eq. 5): ``w_t (q . o) + w_s ŵ[floor(S_in t)]`` with
-  ``S_in = 1 - clip(dist / dist_max, 0, 1)`` and ``ŵ`` the cumulative sum
-  of ``softplus`` of the step increments.
+* the LIST head (mixing weights, router, score): ``list_head``, which
+  this module re-exports.
 
 Every matmul runs at ``Precision.HIGHEST``. ``precision="fp8"`` (the
 control) rounds both operands of every tower matmul to float8 e4m3 with a
@@ -22,15 +19,8 @@ per-tensor scale first.
 The weights are made here, on the device, from a key: every dense layer
 ``N(0, 1/fan_in)``, biases ``N(0, 0.02)``, LayerNorm gains
 ``1 + N(0, 0.1)`` and offsets ``N(0, 0.02)``, token embeddings
-``N(0, 1/d)``, position embeddings ``N(0, 0.02)``, step increments
-``-2 + N(0, 0.01)``. A random tower maps every query to nearly the same
-direction, so a random router sends almost every query to a handful of
-clusters; a trained LIST router spreads them over its balanced clusters.
-``calibrate_router`` stands in for that training: over a seeded sample
-of queries it standardizes the router's input features (the first
-layer sees each feature at zero mean and unit variance) and centres
-each cluster's logit, so routes spread over the clusters as the
-queries' own features differ.
+``N(0, 1/d)``, position embeddings ``N(0, 0.02)``; the head's as
+``list_head`` draws them.
 """
 from __future__ import annotations
 
@@ -38,6 +28,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from chipbench.references import list_head
+from chipbench.references.list_head import (  # noqa: F401 - the contract
+    mixing_weights, router_logits, score, step_table)
 
 HIGHEST = jax.lax.Precision.HIGHEST
 FP8 = jnp.float8_e4m3fn
@@ -47,13 +42,6 @@ FP8_MAX = 448.0
 # ---------------------------------------------------------------------------
 # Weights
 # ---------------------------------------------------------------------------
-
-
-def _dense(key, n_in, n_out):
-    kw, kb = jax.random.split(key)
-    return (jax.random.normal(kw, (n_in, n_out), jnp.float32)
-            / math.sqrt(n_in),
-            0.02 * jax.random.normal(kb, (n_out,), jnp.float32))
 
 
 def _norm(key, d):
@@ -66,17 +54,12 @@ def _layer(key, d, d_ff):
     ks = jax.random.split(key, 8)
     p = {}
     for name, k in zip(("q", "k", "v", "o"), ks[:4]):
-        p["w" + name], p["b" + name] = _dense(k, d, d)
-    p["w1"], p["b1"] = _dense(ks[4], d, d_ff)
-    p["w2"], p["b2"] = _dense(ks[5], d_ff, d)
+        p["w" + name], p["b" + name] = list_head.dense(k, d, d)
+    p["w1"], p["b1"] = list_head.dense(ks[4], d, d_ff)
+    p["w2"], p["b2"] = list_head.dense(ks[5], d_ff, d)
     p["ln1_g"], p["ln1_b"] = _norm(ks[6], d)
     p["ln2_g"], p["ln2_b"] = _norm(ks[7], d)
     return p
-
-
-def _mlp(key, dims):
-    keys = jax.random.split(key, len(dims) - 1)
-    return [_dense(k, a, b) for k, a, b in zip(keys, dims[:-1], dims[1:])]
 
 
 def init_weights(key, cfg):
@@ -86,7 +69,7 @@ def init_weights(key, cfg):
     layers = jax.vmap(lambda kk: _layer(kk, d, cfg["d_ff"]))(
         jax.random.split(k[0], cfg["n_layers"]))
     lnf_g, lnf_b = _norm(k[3], d)
-    cls_w, cls_b = _dense(k[4], d, d)
+    cls_w, cls_b = list_head.dense(k[4], d, d)
     return {
         "tok_emb": jax.random.normal(k[1], (cfg["vocab_size"], d),
                                      jnp.float32) / math.sqrt(d),
@@ -94,11 +77,7 @@ def init_weights(key, cfg):
                                             jnp.float32),
         "layers": layers, "lnf_g": lnf_g, "lnf_b": lnf_b,
         "cls_w": cls_w, "cls_b": cls_b,
-        "weight_mlp": _mlp(k[5], (d, cfg["weight_mlp_hidden"], 2)),
-        "router": _mlp(k[6], (d + 2, *cfg["index_mlp_hidden"],
-                              cfg["n_clusters"])),
-        "w_s": -2.0 + 0.01 * jax.random.normal(k[7], (cfg["spatial_t"],),
-                                               jnp.float32),
+        **list_head.init_weights(k[5], k[6], k[7], cfg, d),
     }
 
 
@@ -158,53 +137,31 @@ def encode(w, tokens, mask, cfg, precision="f32"):
                     + w["cls_b"])
 
 
-def _mlp_apply(layers, x):
-    for i, (wi, bi) in enumerate(layers):
-        x = jnp.matmul(x, wi, precision=HIGHEST) + bi
-        if i < len(layers) - 1:
-            x = jax.nn.relu(x)
-    return x
-
-
-def mixing_weights(w, q):
-    """Eq. 6: (B, d) → (B, 2) positive (textual, spatial) weights."""
-    return jax.nn.softplus(_mlp_apply(w["weight_mlp"], q))
-
-
-def router_logits(w, q, q_loc):
-    """Eq. 9-11: (B, d), (B, 2) in the unit box → (B, c) cluster logits."""
-    e = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-9)
-    return _mlp_apply(w["router"], jnp.concatenate([e, q_loc], -1))
-
-
 def calibrate_router(w, tokens, mask, q_loc, cfg):
-    """``w`` with its router standardized and centred over a sample of
-    queries (see the module docstring)."""
-    q = encode(w, tokens, mask, cfg)
-    e = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-9)
-    x = jnp.concatenate([e, q_loc], -1)
-    mu, sd = x.mean(0), x.std(0) + 1e-6
-    (w0, b0), *rest = w["router"]
-    router = [(w0 / sd[:, None],
-               b0 - jnp.matmul(mu / sd, w0, precision=HIGHEST)), *rest]
-    logits = _mlp_apply(router, x)
-    wl, bl = router[-1]
-    router[-1] = (wl, bl - logits.mean(0))
-    return {**w, "router": router}
+    """``w`` with its router calibrated over queries this tower encodes
+    (``list_head.calibrate_router``)."""
+    return list_head.calibrate_router(w, tokens, mask, q_loc, cfg, encode)
 
 
-def step_table(w):
-    return jnp.cumsum(jax.nn.softplus(w["w_s"]))
+# ---------------------------------------------------------------------------
+# Work
+# ---------------------------------------------------------------------------
 
 
-def score(q, q_loc, mix, emb, loc, table, dist_max):
-    """Eq. 5 for every (query, row): q (B, d), q_loc (B, 2), mix (B, 2)
-    against emb (..., N, d) f32 and loc (..., N, 2) → (B, ..., N)."""
-    trel = jnp.einsum("bd,...nd->b...n", q, emb, precision=HIGHEST)
-    diff = q_loc.reshape((q.shape[0],) + (1,) * (loc.ndim - 1) + (2,)) - loc
-    dist = jnp.sqrt(jnp.sum(diff * diff, -1))
-    s_in = 1.0 - jnp.clip(dist / dist_max, 0.0, 1.0)
-    t = table.shape[0]
-    srel = table[jnp.clip(jnp.floor(s_in * t).astype(jnp.int32), 0, t - 1)]
-    shape = (q.shape[0],) + (1,) * (trel.ndim - 1)
-    return mix[:, 0].reshape(shape) * trel + mix[:, 1].reshape(shape) * srel
+def tower_dense_flops_per_token(cfg):
+    """Projection and feed-forward FLOPs of one token through the tower:
+    per layer ``2 * (4 d^2 + 2 d d_ff)``."""
+    d, f = cfg["d_model"], cfg["d_ff"]
+    return 2 * (4 * d * d + 2 * d * f) * cfg["n_layers"]
+
+
+def query_flops(cfg, lengths):
+    """FLOPs one query of ``lengths`` real tokens needs before the scan:
+    the tower's projections and feed-forward per token, attention scores
+    and values (``4 n d`` per token per layer), and the head (the CLS
+    dense, the mixing MLP and the router MLP). ``lengths`` may be an
+    array (summed)."""
+    n = np.asarray(lengths, np.float64)
+    d, layers = cfg["d_model"], cfg["n_layers"]
+    tower = n * tower_dense_flops_per_token(cfg) + 4.0 * n * n * d * layers
+    return float(np.sum(tower + list_head.flops_per_query(cfg, d, d)))

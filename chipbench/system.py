@@ -1,71 +1,48 @@
 """The system under test, driven through its own entry points.
 
-This is the only module of the benchmark that imports the program
-(``repro``): it hands the benchmark's weights and buffers to
-``IndexSnapshot.from_parts``, serves through ``Searcher.serve``
+This module and the tower adapters (``towers/<reference>.py``) are the
+only modules of a run that import the program (``repro``). It hands the
+benchmark's weights and buffers to ``IndexSnapshot.from_parts``, through
+the configuration's tower adapter, serves through ``Searcher.serve``
 (``StreamingServer.submit``) or ``Searcher.query``, and records a host
 span around every call the server or the window makes into
-``QueryEngine.query``.
+``QueryEngine.query``. Nothing here depends on the tower.
 """
 from __future__ import annotations
 
 import asyncio
 import dataclasses
+import importlib
 import math
+import pathlib
 import time
 
 import numpy as np
 
-
-def program_config(model):
-    from repro.configs import get_config
-    return dataclasses.replace(
-        get_config(model["arch"]),
-        n_layers=model["n_layers"], d_model=model["d_model"],
-        n_heads=model["n_heads"], d_ff=model["d_ff"],
-        vocab_size=model["vocab_size"], max_len=model["max_len"],
-        norm_eps=model["norm_eps"], param_dtype=model["param_dtype"],
-        compute_dtype=model["compute_dtype"], spatial_t=model["spatial_t"],
-        n_clusters=model["n_clusters"],
-        index_mlp_hidden=tuple(model["index_mlp_hidden"]))
+BENCH = pathlib.Path(__file__).resolve().parent
 
 
-def _dense(w, b):
-    return {"w": w, "b": b}
+def tower_adapter(config):
+    """The module that hands the program the tower of the configuration's
+    reference: ``towers/<reference>.py``. Raises ``LookupError``, naming
+    the file to add, when there is none."""
+    name = config["reference"]
+    if not (BENCH / "towers" / f"{name}.py").is_file():
+        raise LookupError(
+            f"no tower adapter for reference {name!r}: add "
+            f"chipbench/towers/{name}.py with program_config(model) and "
+            "program_params(weights)")
+    return importlib.import_module(f"chipbench.towers.{name}")
 
 
-def program_params(w):
-    """The benchmark's weights in the program's pytree layout (the same
-    device arrays, no copy). → (rel_params, index_params, norm)."""
-    import jax.numpy as jnp
-    ly = w["layers"]
-    tower = {
-        "embed": w["tok_emb"], "pos_embed": w["pos_emb"],
-        "blocks": {
-            "ln1": {"scale": ly["ln1_g"], "bias": ly["ln1_b"]},
-            "ln2": {"scale": ly["ln2_g"], "bias": ly["ln2_b"]},
-            "attn": {n: _dense(ly[n], ly["b" + n[1]])
-                     for n in ("wq", "wk", "wv", "wo")},
-            "mlp": {"w1": _dense(ly["w1"], ly["b1"]),
-                    "w2": _dense(ly["w2"], ly["b2"])}},
-        "final_ln": {"scale": w["lnf_g"], "bias": w["lnf_b"]},
-        "cls": _dense(w["cls_w"], w["cls_b"]),
-    }
-    rel = {"q_enc": tower, "o_enc": tower,
-           "weight_mlp": [_dense(*p) for p in w["weight_mlp"]],
-           "fixed_w": jnp.ones((2,), jnp.float32),
-           "spatial": {"w_s": w["w_s"]}}
-    index = {"mlp": [_dense(*p) for p in w["router"]]}
-    norm = {"lo": jnp.zeros((2,), jnp.float32),
-            "span": jnp.ones((2,), jnp.float32)}
-    return rel, index, norm
-
-
-def searcher(model, weights, buffers):
+def searcher(config, weights, buffers):
+    """The program's ``Searcher`` over the benchmark's weights and index,
+    its tower built by the configuration's tower adapter."""
     from repro import api
     from repro.core.snapshot import IndexSnapshot
-    snap = IndexSnapshot.from_parts(program_config(model),
-                                    *program_params(weights), buffers,
+    tower = tower_adapter(config)
+    snap = IndexSnapshot.from_parts(tower.program_config(config["model"]),
+                                    *tower.program_params(weights), buffers,
                                     dist_max=math.sqrt(2.0))
     return api.Searcher(snap)
 

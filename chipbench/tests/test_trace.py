@@ -5,6 +5,7 @@ import gzip
 import json
 import pathlib
 
+import numpy as np
 import pytest
 
 from chipbench import trace as trace_lib
@@ -74,3 +75,58 @@ def test_recorded_chip_trace_reduces_as_when_recorded():
     per_step = trace_lib.step_device_ns(tr)
     assert sum(b for b, _ in per_step) == pytest.approx(busy)
     assert sum(k for _, k in per_step) == pytest.approx(kern)
+
+
+def _step_device_ns_by_union(trace):
+    """Per step, the union of every device's operations clipped to the
+    step, recomputed for each step: what ``step_device_ns`` returned
+    before it merged each device's operations once."""
+    out = []
+    names = set(trace.kernel_names)
+    k = max(len(trace.devices), 1)
+    for a, b in trace_lib.step_windows(trace):
+        busy = kern = 0
+        for dev in trace.devices:
+            busy += trace_lib.union_ns([(e[1], e[2]) for e in dev], a, b)
+            kern += trace_lib.union_ns(
+                [(e[1], e[2]) for e in dev if e[0] in names], a, b)
+        out.append((busy / k, kern / k))
+    return out
+
+
+def overlapping_trace(seed):
+    """Two devices of overlapping, nested, touching and empty operations,
+    and steps that overlap each other, touch operations' ends, are empty
+    or lie outside every operation."""
+    rng = np.random.default_rng(seed)
+    devices = []
+    for _ in range(2):
+        starts = np.sort(rng.integers(0, 5000, 400))
+        ops = [(str(rng.choice(["k.1", "fusion.1", "k.2", "copy.1"])),
+                int(s), int(rng.choice([0, 1, 5, 40, 300])))
+               for s in starts]
+        ops += [("k.1", 100, 50), ("fusion.1", 150, 50), ("k.2", 120, 10)]
+        devices.append(sorted(ops, key=lambda e: e[1]))
+    steps = [("chipbench.step", int(a), int(d)) for a, d in zip(
+        rng.integers(-200, 5600, 60), rng.choice([0, 1, 50, 400, 3000], 60))]
+    steps += [("chipbench.step", 150, 50), ("chipbench.step", 200, 0),
+              ("chipbench.step", 6000, 100)]
+    host = sorted([("chipbench.window", -500, 7000)] + steps,
+                  key=lambda e: e[1])
+    return trace_lib.Trace(devices, host, ["k.1", "k.2"])
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_step_device_ns_equals_the_union_per_step_on_overlaps(seed):
+    tr = overlapping_trace(seed)
+    got = trace_lib.step_device_ns(tr)
+    assert len(got) == 63
+    assert got == _step_device_ns_by_union(tr)
+
+
+@pytest.mark.skipif(not FIXTURE.exists(), reason="no recorded trace")
+def test_step_device_ns_equals_the_union_per_step_on_the_chip_trace():
+    with gzip.open(FIXTURE, "rt") as f:
+        tr = trace_lib.Trace.from_json(json.load(f))
+    got = trace_lib.step_device_ns(tr)
+    assert got and got == _step_device_ns_by_union(tr)

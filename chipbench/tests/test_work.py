@@ -1,4 +1,6 @@
-"""Work counts at the published widths, against hand counts."""
+"""Work counts at the published widths, against hand counts: the scan's
+(``work.py``) and the query phase's, which the tower's reference counts
+(``references/list_dual_encoder.py``, ``query_flops``)."""
 import json
 import pathlib
 
@@ -6,6 +8,7 @@ import numpy as np
 import pytest
 
 from chipbench import work
+from chipbench.references import list_dual_encoder as ref
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 
@@ -17,7 +20,11 @@ def model():
 
 def test_tower_is_170_mflop_per_token_per_pass():
     # 12 layers x 2 x (4 x 768^2 + 2 x 768 x 3072)
-    assert work.tower_dense_flops_per_token(model()) == 169_869_312
+    m = model()
+    assert ref.tower_dense_flops_per_token(m) == 169_869_312
+    # one more token: its pass, and its attention (4 x 768 x 12 at n = 1)
+    assert (ref.query_flops(m, [1]) - ref.query_flops(m, [0])
+            == 169_869_312 + 4 * 768 * 12)
 
 
 def test_query_flops_add_attention_head_mixing_and_router():
@@ -26,8 +33,8 @@ def test_query_flops_add_attention_head_mixing_and_router():
     want = (n * 169_869_312 + 4 * n * n * 768 * 12 + 2 * 768 * 768
             + 2 * (768 * 64 + 64 * 2)
             + 2 * (770 * 512 + 512 * 512 + 512 * 300))
-    assert work.query_flops(m, [n]) == pytest.approx(want)
-    assert work.query_flops(m, [n, n]) == pytest.approx(2 * want)
+    assert ref.query_flops(m, [n]) == want
+    assert ref.query_flops(m, [n, n]) == 2 * want
 
 
 @pytest.mark.parametrize("precision,row", [("bf16", 768 * 2 + 8 + 4),

@@ -15,6 +15,7 @@ be kept as a small JSON fixture and reduced again by a test.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
 import os
@@ -213,19 +214,51 @@ def step_windows(trace):
     return [(s, s + d) for _, s, d in trace.spans(SPAN_PREFIX + "step")]
 
 
+def _merged(intervals):
+    """The union of ``(start, duration)`` intervals as disjoint, sorted
+    ``(starts, ends, lengths before each)``, for :func:`_covered_ns`."""
+    starts, ends = [], []
+    for s, d in sorted(intervals):
+        e = s + d
+        if e <= s:
+            continue
+        if ends and s <= ends[-1]:
+            ends[-1] = max(ends[-1], e)
+        else:
+            starts.append(s)
+            ends.append(e)
+    before = [0]
+    for s, e in zip(starts, ends):
+        before.append(before[-1] + e - s)
+    return starts, ends, before
+
+
+def _covered_ns(merged, a, b):
+    """Length of ``[a, b]`` that a :func:`_merged` union covers (what
+    ``union_ns(intervals, a, b)`` gives), by bisection."""
+    starts, ends, before = merged
+    i = bisect.bisect_right(ends, a)        # first piece ending past a
+    j = bisect.bisect_left(starts, b)       # pieces [i, j) start before b
+    if i >= j or b <= a:
+        return 0
+    return (before[j] - before[i] - max(0, a - starts[i])
+            - max(0, ends[j - 1] - b))
+
+
 def step_device_ns(trace):
     """Per ``chipbench.step`` span: (device busy ns, kernel ns) inside it,
     averaged over the devices. The host spans and the device operations
     share the trace's clock, so a step owns the device work that runs
-    while its call is in flight."""
-    out = []
+    while its call is in flight. Each device's operations are merged once,
+    so the cost grows with steps plus operations, not their product."""
     names = set(trace.kernel_names)
     k = max(len(trace.devices), 1)
+    merged = [(_merged([(e[1], e[2]) for e in dev]),
+               _merged([(e[1], e[2]) for e in dev if e[0] in names]))
+              for dev in trace.devices]
+    out = []
     for a, b in step_windows(trace):
-        busy = kern = 0
-        for dev in trace.devices:
-            busy += union_ns([(e[1], e[2]) for e in dev], a, b)
-            kern += union_ns([(e[1], e[2]) for e in dev if e[0] in names],
-                             a, b)
+        busy = sum(_covered_ns(m, a, b) for m, _ in merged)
+        kern = sum(_covered_ns(m, a, b) for _, m in merged)
         out.append((busy / k, kern / k))
     return out
